@@ -377,6 +377,9 @@ def check(report, baseline_path, tol=1.10):
 
 def main():
     import os
+    # the 8-host-device sweep runs on the CPU only: pin the platform
+    # before any backend starts so neither it nor a child takes a chip
+    os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if "host_platform_device_count" not in flags:
         # must happen before the first jax import (measure imports lazily)

@@ -13,6 +13,7 @@ import numpy as np
 
 from benchmarks import table4_accuracy
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.train.train_step import make_train_state, make_train_step
@@ -41,6 +42,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=40)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("== Table IV reproduction (relative error vs FP64 golden) ==")
     table4_accuracy.main(trials=15)

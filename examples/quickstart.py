@@ -23,6 +23,7 @@ from repro.core import exsdotp as X
 from repro.core import formats as F
 from repro.core.policy import POLICIES
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import format_packed_footprint
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
@@ -32,6 +33,7 @@ from repro.train.train_step import make_train_state, make_train_step
 ap = argparse.ArgumentParser()
 ap.add_argument("--policy", default="hfp8", choices=sorted(POLICIES))
 ARGS = ap.parse_args()
+enable_compile_cache()
 
 print("=" * 64)
 print("1) ExSdotp: fused 3-term add beats the ExFMA cascade")

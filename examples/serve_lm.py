@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs import ARCHS
 from repro.configs.base import ModelConfig
 from repro.core.policy import POLICIES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import format_serve_cache_footprint
 from repro.models import build_model
 from repro.serve.scheduler import ContinuousBatcher, ServeRequest
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--max-len", type=int, default=64)
     ap.add_argument("--page-size", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # CPU-sized variant of the real arch; head_dim widened to a whole
     # scale group so the MX policies serve *packed* pages (reduced()

@@ -17,6 +17,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.policy import POLICIES
 from repro.data.pipeline import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import format_packed_footprint
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
@@ -42,6 +43,7 @@ def main():
     ap.add_argument("--save-every", type=int, default=25)
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     p = PRESETS[args.preset]
     cfg = ModelConfig(

@@ -1,73 +1,18 @@
-"""Cross-version jax API shims.
-
-The codebase targets the modern jax API surface; installed images can lag
-by several minor versions.  Every spot that touches a recently-renamed
-symbol goes through here so the rest of the tree stays on one spelling.
-
-Covered:
-  * ``shard_map``          — ``jax.shard_map`` vs ``jax.experimental.shard_map``
-  * ``make_mesh``          — ``axis_types=`` kwarg only exists on newer jax
-  * ``set_mesh``           — ``jax.set_mesh`` vs the ``Mesh`` context manager
-"""
+"""The three mesh/partitioning spellings the tree uses, pinned to the
+installed JAX (0.9): ``shard_map``, ``make_mesh`` with every axis
+``Auto`` (GSPMD may still partition over it), and ``set_mesh``."""
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.6 re-exports shard_map at top level
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
+from jax import shard_map, set_mesh
 
 __all__ = ["shard_map", "make_mesh", "set_mesh"]
 
-_SHARD_MAP_PARAMS = frozenset(
-    inspect.signature(_shard_map_raw).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=None):
-    """``shard_map`` accepting the modern kwargs on any jax.
-
-    * ``check_vma``   — called ``check_rep`` before jax 0.6;
-    * ``axis_names``  — the manual axes; older jax expresses the same set
-      as its complement, ``auto`` (mesh axes left under GSPMD).
-    """
-    kwargs = {}
-    if "axis_names" in _SHARD_MAP_PARAMS:  # modern spelling
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-    else:
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kwargs["auto"] = auto
-    return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    New jax spells this ``jax.set_mesh``; older releases use the ``Mesh``
-    object itself as the context manager.  **Always use the return value
-    with ``with``** — on older jax nothing happens until the context is
-    entered, so a bare call is a silent no-op there.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with Auto axis types (the sharding rules in
+    ``parallel/sharding.py`` place arrays explicitly and leave the rest
+    to GSPMD)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))

@@ -373,9 +373,11 @@ def encode(x: jax.Array, fmt) -> jax.Array:
                 >> (23 - fmt.man_bits)).astype(jnp.uint32)
     exp_norm = jnp.clip(e + fmt.bias, 0, (1 << fmt.exp_bits) - 1)
     # subnormals (and zero): value = man * min_subnormal, exact pow2 ratio
+    # (float -> int32 -> uint32: Mosaic has no direct f32 -> u32 cast)
     man_sub = jnp.round(
         aq * _exact_pow2(jnp.full(q.shape, fmt.man_bits - fmt.min_exp,
-                                  jnp.int32))).astype(jnp.uint32)
+                                  jnp.int32))).astype(jnp.int32).astype(
+                                      jnp.uint32)
     exp_field = jnp.where(sub, 0, exp_norm).astype(jnp.uint32)
     man_field = jnp.where(sub, man_sub, man_norm)
     top = jnp.uint32((1 << fmt.exp_bits) - 1)

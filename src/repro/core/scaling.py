@@ -28,8 +28,8 @@ import jax.numpy as jnp
 
 __all__ = ["loss_scale_init", "check_and_update_scale",
            "BlockScaleConfig", "compute_block_scales", "apply_block_scales",
-           "compute_group_scales", "apply_group_scales",
-           "expand_group_scales",
+           "compute_group_scales", "group_scales_from_amax",
+           "apply_group_scales", "expand_group_scales", "pow2_reciprocal",
            "block_loss_scale_init", "check_and_update_block_scales"]
 
 
@@ -164,7 +164,15 @@ def compute_group_scales(x: jax.Array, group: int, elem_max: float,
     *lead, k = x.shape
     assert k % group == 0, (k, group)
     xg = jnp.abs(x.astype(jnp.float32)).reshape(*lead, k // group, group)
-    amax = jnp.max(xg, axis=-1)
+    return group_scales_from_amax(jnp.max(xg, axis=-1), elem_max,
+                                  nan_scale=nan_scale)
+
+
+def group_scales_from_amax(amax: jax.Array, elem_max: float,
+                           *, nan_scale: bool = True) -> jax.Array:
+    """The E8M0 scale formula of ``compute_group_scales`` applied to
+    group amaxes already reduced (at any resolution — the Pallas
+    kernels reduce in-register and keep one amax per element)."""
     s = _pow2_ceil(jnp.maximum(amax / jnp.float32(elem_max),
                                jnp.float32(2.0 ** -126)))
     s = jnp.where(amax > 0, s, jnp.float32(1.0))
@@ -188,7 +196,22 @@ def apply_group_scales(x: jax.Array, s: jax.Array, group: int,
     ``group``-element strip (``inverse=True`` divides — the quantize
     direction).  Exact for pow2 scales."""
     se = expand_group_scales(s, group).reshape(x.shape)
-    return x / se if inverse else x * se
+    return x * pow2_reciprocal(se) if inverse else x * se
+
+
+def pow2_reciprocal(s: jax.Array) -> jax.Array:
+    """``1 / s`` for power-of-two f32 scales in [2^-126, 2^126] (every
+    E8M0 group scale), exact by construction: the biased exponent
+    flips to ``254 - e``.  NaN stays NaN.  Quantizing is then ``x *
+    pow2_reciprocal(s)``, the correctly rounded ``x / s`` on every
+    backend — a TPU divide is a reciprocal estimate plus refinement,
+    and a last-bit slip there moves a rounding tie of the narrow cast.
+    """
+    bits = jax.lax.bitcast_convert_type(s.astype(jnp.float32), jnp.uint32)
+    e = (bits >> 23) & jnp.uint32(0xFF)
+    r = jax.lax.bitcast_convert_type((jnp.uint32(254) - e) << 23,
+                                     jnp.float32)
+    return jnp.where(jnp.isnan(s), s, r)
 
 
 def loss_scale_init(initial: float = 2.0 ** 15):

@@ -52,11 +52,14 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 import math
 import os
 import time
 
 import numpy as np
+
+_log = logging.getLogger(__name__)
 
 __all__ = ["TuneResult", "autotune", "peek", "clear_memo", "cache_dir",
            "time_us_median", "gemm_tile_candidates",
@@ -185,27 +188,32 @@ def autotune(kernel: str, key: str, candidates, bench_fn, *,
     winner is persisted under ``<cache_dir>/<kernel>.json`` keyed by
     ``key``; a later call with the same key returns it without invoking
     ``bench_fn`` at all (cache-hit determinism).  A candidate whose
-    bench raises is skipped (scored +inf); if every candidate fails the
-    first candidate is returned unpersisted with source 'default'.
+    bench raises (a tile the compiler refuses, say) is skipped and
+    logged with its error; if every candidate fails, the errors are
+    raised together — a sweep never silently ends on an untested tile.
     """
     candidates = [tuple(c) for c in candidates]
     assert candidates, kernel
     hit = peek(kernel, key, cache_dir=cache_dir)
     if hit is not None and tuple(hit.tiles) in candidates:
         return hit
-    best, best_us = None, math.inf
+    best, best_us, errors = None, math.inf, []
     for cand in candidates:
         try:
             for _ in range(max(warmup, 0)):
                 bench_fn(cand)
             us = float(np.median([bench_fn(cand)
                                   for _ in range(max(iters, 1))]))
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — reported, then re-raised
+            errors.append(f"{cand}: {type(e).__name__}: {e}")
+            _log.warning("autotune %s: candidate %s skipped: %s", kernel,
+                         cand, errors[-1])
             continue
         if us < best_us:
             best, best_us = cand, us
     if best is None:
-        return TuneResult(candidates[0], None, "default")
+        raise RuntimeError(f"autotune {kernel} [{key}]: every candidate "
+                           "failed:\n  " + "\n  ".join(errors))
     _store(kernel, key, {"tiles": list(best), "us": best_us}, cache_dir)
     return TuneResult(best, best_us, "swept")
 

@@ -31,7 +31,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import _quantize_f32, e8m0_decode, get_mx_format
-from ._compat import CompilerParams
 from .codec import get_codec
 
 __all__ = ["blockscale_gemm_pallas", "mx_gemm_pallas",
@@ -135,7 +134,7 @@ def blockscale_gemm_pallas(a: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, sa.astype(jnp.float32), sb.astype(jnp.float32))
@@ -238,7 +237,7 @@ def mx_gemm_pallas(a: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, sae.astype(jnp.float32), sbe.astype(jnp.float32))
@@ -276,8 +275,8 @@ def _mx_packed_gemm_kernel(ap_ref, bp_ref, sa8_ref, sb8_ref, o_ref, acc_ref,
 
     # in-register unpack + decode + E8M0 dequant: the packed bytes are
     # the only operand representation VMEM ever holds
-    av = codec_a.decode_lanes(ap_ref[...]) * e8m0_decode(sa8_ref[...])
-    bv = codec_b.decode_lanes(bp_ref[...]) * e8m0_decode(sb8_ref[...])
+    av = codec_a.decode_tile(ap_ref[...]) * e8m0_decode(sa8_ref[...])
+    bv = codec_b.decode_tile(bp_ref[...]) * e8m0_decode(sb8_ref[...])
     acc_ref[...] += jax.lax.dot_general(
         av, bv, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -348,8 +347,8 @@ def _mx_packed_gemm_db_kernel(ap_hbm, bp_hbm, sa_hbm, sb_hbm, o_ref,
             d.wait()
         # in-register unpack + decode + E8M0 dequant — same fold point,
         # same accumulation order as the grid-pipelined kernel
-        av = codec_a.decode_lanes(ap_s[cur]) * e8m0_decode(sa_s[cur])
-        bv = codec_b.decode_lanes(bp_s[cur]) * e8m0_decode(sb_s[cur])
+        av = codec_a.decode_tile(ap_s[cur]) * e8m0_decode(sa_s[cur])
+        bv = codec_b.decode_tile(bp_s[cur]) * e8m0_decode(sb_s[cur])
         acc_ref[...] += jax.lax.dot_general(
             av, bv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -419,7 +418,7 @@ def mx_gemm_packed_pallas(ap: jax.Array, bp: jax.Array,
         return pl.pallas_call(
             kern,
             grid=(m // block_m, n // block_n),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
             out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
             scratch_shapes=[
@@ -430,7 +429,7 @@ def mx_gemm_packed_pallas(ap: jax.Array, bp: jax.Array,
                 pltpu.VMEM((block_m, block_n), jnp.float32),
                 pltpu.SemaphoreType.DMA((4, 2)),
             ],
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(ap, bp, sae8, sbe8)
@@ -447,7 +446,7 @@ def mx_gemm_packed_pallas(ap: jax.Array, bp: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ap, bp, sae8, sbe8)

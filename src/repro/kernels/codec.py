@@ -134,6 +134,21 @@ class PayloadCodec:
         ``unpack_decode_np``."""
         return F.decode(self.unpack_lanes(payload), self.fmt)
 
+    # ---- the same codec inside a compiled kernel (2-D tiles) ----------
+    def encode_tile(self, values: jax.Array) -> jax.Array:
+        """``encode_lanes`` for a ``[rows, K]`` kernel tile: the pack
+        step routes bit fields with selection matmuls
+        (``pack.pack_codes_tile``), which Mosaic lowers where the
+        strided lane slices of ``pack_lanes`` do not.  Bit-identical."""
+        return packlib.pack_codes_tile(F.encode(values, self.fmt),
+                                       self.width)
+
+    def decode_tile(self, payload: jax.Array) -> jax.Array:
+        """``decode_lanes`` for a ``[rows, B]`` kernel tile (see
+        ``encode_tile``).  Bit-identical."""
+        return F.decode(packlib.unpack_codes_tile(payload, self.width),
+                        self.fmt)
+
     def __str__(self) -> str:  # pragma: no cover - cosmetic
         return (f"codec({self.fmt.name}: {self.elems_per_word} elems / "
                 f"{self.word_bytes} B)")

@@ -10,8 +10,10 @@ Both kernels reuse the flash-attention shell (``_kernel``/``_call``)
 with two decode-specific twists threaded through the shared
 online-softmax core:
 
-* **base offset** — the per-sequence length enters as a scalar operand
-  (``[BH, 1]`` int32, one per batch·head row); q row ``i`` sits at
+* **base offset** — the per-sequence lengths enter as one ``[BH]``
+  int32 operand held whole in SMEM (a ``[BH, 1]`` VMEM block would
+  break the (8, 128) tiling rule), read at the grid's batch·head
+  coordinate; q row ``i`` sits at
   absolute cache slot ``base + i``, so the causal mask is
   ``col <= base + row`` and the carry-skip condition gains ``+ base``
   — with a dynamic base the skip doubles as a *page-skip*: KV tiles
@@ -25,14 +27,16 @@ online-softmax core:
 
 ``mx_decode_attention_pallas`` streams the cache as *packed* codec
 payloads + E8M0 scale codes and decodes groups in-register beside the
-f32 (m, l, acc) accumulators — the same ``codec.decode_lanes`` fold
+f32 (m, l, acc) accumulators — the same ``codec.decode_tile`` fold
 point as ``mx_flash_attention_pallas``.  ``decode_attention_pallas``
 is the carrier-precision variant (the bf16 page-pool fallback).
 
 Compiled-TPU lane legality follows the §11 convention: packed payload
-rows must be 128-byte multiples and S=1 gives a sublane-short q tile —
-interp/CPU CI masks violations; real-TPU serving pads the head axis at
-the layer above.
+rows must be 128-byte multiples (hd a whole number of groups), and a q
+tile is either the whole S=1 row or a sublane 8-multiple.  A q tile
+that does not divide S is legal: the wrapper pads q's rows and keeps
+the garbage limit at the true ``base + S``, so padded rows never widen
+the live prefix real rows see.
 """
 from __future__ import annotations
 
@@ -41,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import e8m0_decode, get_mx_format
 from .codec import get_codec
@@ -49,10 +54,20 @@ from .flash_attention import _call, _kernel
 __all__ = ["decode_attention_pallas", "mx_decode_attention_pallas"]
 
 
-def _lens2d(lens, bh):
+#: lens [BH] int32, whole in SMEM (scalar reads at program_id(0))
+_LENS_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _prepare(q, lens, block_q):
+    """Shape-check ``lens`` and zero-pad q's rows to a ``block_q``
+    multiple; returns ``(q, lens, live_rows)``."""
+    bh, s, _ = q.shape
     lens = jnp.asarray(lens, jnp.int32)
     assert lens.shape == (bh,), (lens.shape, bh)
-    return lens.reshape(bh, 1)
+    pad = (-s) % block_q
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0)))
+    return q, lens, s
 
 
 def _mask_garbage(k, v, kk, limit, block_k):
@@ -81,26 +96,26 @@ def decode_attention_pallas(q, k, v, lens, *, block_q: int = 8,
     and excluded structurally.  ``debug_visited=True`` additionally
     returns the int32 [BH, S/bq, T/bk] visit grid (page-skip tests).
 
-    Tile-legality contract (DESIGN.md §12/§14): ``block_q`` | S and
-    ``block_k`` | T exactly (positional mask — assert, don't pad).  The
-    decode q axis may fall below the sublane unit, down to ``block_q=1``
-    (S=1 steady-state decode) — interpret/CPU-only below 8; real-TPU
-    serving picks aligned page sizes (``ops.decode_attention_blocks`` /
-    the §14 autotuner, floors 1 and 8).
+    Tile-legality contract (DESIGN.md §12/§14): ``block_k`` | T
+    exactly (positional mask — assert, don't pad).  q rows are padded
+    to a ``block_q`` multiple (the live limit stays ``lens + S``).  On
+    compiled TPU ``block_q`` is 1 only for S=1 and a sublane 8-multiple
+    otherwise (``ops.decode_attention`` picks so); smaller q tiles are
+    interpret/CPU-only.
     """
-    bh, s, hd = q.shape
+    q, lens, live = _prepare(q, lens, block_q)
+    hd = q.shape[-1]
     t = k.shape[1]
-    assert s % block_q == 0 and t % block_k == 0, ((s, t),
-                                                   (block_q, block_k))
+    assert t % block_k == 0, (t, block_k)
 
     def load_kv(refs):
         lens_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
-        base = lens_ref[0, 0]
+        base = lens_ref[pl.program_id(0)]
 
-        def loader(kk, limit):
+        def loader(kk):
             return _mask_garbage(k_ref[0].astype(jnp.float32),
                                  v_ref[0].astype(jnp.float32),
-                                 kk, limit, block_k)
+                                 kk, base + live, block_k)
 
         return loader, base, refs[3:]
 
@@ -108,12 +123,13 @@ def decode_attention_pallas(q, k, v, lens, *, block_q: int = 8,
         _kernel, load_kv=load_kv, causal=True, scale=hd ** -0.5,
         block_q=block_q, block_k=block_k, skip_masked=skip_masked,
         debug_visited=debug_visited)
-    specs = [pl.BlockSpec((1, 1), lambda b, i, kk: (b, 0)),
+    specs = [_LENS_SPEC,
              pl.BlockSpec((1, block_k, hd), lambda b, i, kk: (b, kk, 0)),
              pl.BlockSpec((1, block_k, hd), lambda b, i, kk: (b, kk, 0))]
-    return _call(kern, q, (_lens2d(lens, bh), k, v), specs,
-                 block_q=block_q, block_k=block_k, t=t,
-                 debug_visited=debug_visited, interpret=interpret)
+    out = _call(kern, q, (lens, k, v), specs,
+                block_q=block_q, block_k=block_k, t=t,
+                debug_visited=debug_visited, interpret=interpret)
+    return _unpad(out, live, debug_visited)
 
 
 @functools.partial(
@@ -142,19 +158,19 @@ def mx_decode_attention_pallas(q, kp, ks8, vp, vs8, lens, *, mx_k,
     operands (``tests/fuzz.exact_decode_operands``) — the same bar as
     every codec kernel.
 
-    Tile-legality contract: as ``decode_attention_pallas`` (§12/§14 —
-    tiles divide S/T exactly, ``block_q`` down to 1 interp-only), plus
-    hd a whole number of groups so the packed byte run is lane-legal.
+    Tile-legality contract: as ``decode_attention_pallas`` (§12/§14),
+    plus hd a whole number of groups so the packed byte run is
+    lane-legal.
     """
     mx_k = get_mx_format(mx_k)
     mx_v = mx_k if mx_v is None else get_mx_format(mx_v)
     ck, cv = get_codec(mx_k), get_codec(mx_v)
     g = mx_k.group
     assert mx_v.group == g, (mx_k.name, mx_v.name)
-    bh, s, hd = q.shape
+    q, lens, live = _prepare(q, lens, block_q)
+    bh, _, hd = q.shape
     t = kp.shape[1]
-    assert s % block_q == 0 and t % block_k == 0, ((s, t),
-                                                   (block_q, block_k))
+    assert t % block_k == 0, (t, block_k)
     assert hd % g == 0, (hd, g)
     assert kp.shape == (bh, t, ck.packed_cols(hd)), (kp.shape, (bh, t, hd))
     assert vp.shape == (bh, t, cv.packed_cols(hd)), (vp.shape, (bh, t, hd))
@@ -167,12 +183,12 @@ def mx_decode_attention_pallas(q, kp, ks8, vp, vs8, lens, *, mx_k,
     def load_kv(refs):
         lens_ref = refs[0]
         kp_ref, ks_ref, vp_ref, vs_ref = refs[1:5]
-        base = lens_ref[0, 0]
+        base = lens_ref[pl.program_id(0)]
 
-        def loader(kk, limit):
-            k = ck.decode_lanes(kp_ref[0]) * e8m0_decode(ks_ref[0])
-            v = cv.decode_lanes(vp_ref[0]) * e8m0_decode(vs_ref[0])
-            return _mask_garbage(k, v, kk, limit, block_k)
+        def loader(kk):
+            k = ck.decode_tile(kp_ref[0]) * e8m0_decode(ks_ref[0])
+            v = cv.decode_tile(vp_ref[0]) * e8m0_decode(vs_ref[0])
+            return _mask_garbage(k, v, kk, base + live, block_k)
 
         return loader, base, refs[5:]
 
@@ -181,11 +197,19 @@ def mx_decode_attention_pallas(q, kp, ks8, vp, vs8, lens, *, mx_k,
         block_q=block_q, block_k=block_k, skip_masked=skip_masked,
         debug_visited=debug_visited)
     pk, pv = ck.packed_cols(hd), cv.packed_cols(hd)
-    specs = [pl.BlockSpec((1, 1), lambda b, i, kk: (b, 0)),
+    specs = [_LENS_SPEC,
              pl.BlockSpec((1, block_k, pk), lambda b, i, kk: (b, kk, 0)),
              pl.BlockSpec((1, block_k, hd), lambda b, i, kk: (b, kk, 0)),
              pl.BlockSpec((1, block_k, pv), lambda b, i, kk: (b, kk, 0)),
              pl.BlockSpec((1, block_k, hd), lambda b, i, kk: (b, kk, 0))]
-    return _call(kern, q, (_lens2d(lens, bh), kp, ks8e, vp, vs8e), specs,
-                 block_q=block_q, block_k=block_k, t=t,
-                 debug_visited=debug_visited, interpret=interpret)
+    out = _call(kern, q, (lens, kp, ks8e, vp, vs8e), specs,
+                block_q=block_q, block_k=block_k, t=t,
+                debug_visited=debug_visited, interpret=interpret)
+    return _unpad(out, live, debug_visited)
+
+
+def _unpad(out, live, debug_visited):
+    """Drop the padded q rows (the visit grid keeps its tile rows)."""
+    if debug_visited:
+        return out[0][:, :live], out[1]
+    return out[:, :live]

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
+from ..core.formats import decode
 
 __all__ = ["exsdotp_gemm_pallas", "default_blocks"]
 
@@ -43,7 +43,16 @@ def default_blocks(m: int, n: int, k: int, src_bytes: int) -> tuple[int, int, in
     return bm, bn, max(bk, 1)
 
 
-def _kernel(a_ref, b_ref, scale_ref, o_ref, acc_ref):
+def _widen(tile, fmt):
+    """Minifloat tile → f32.  ``fmt`` names the format of a ``uint8``
+    tile holding raw bit patterns (decoded in-register, exactly, specials
+    included); ``None`` means the dtype is native and casts directly."""
+    if fmt is None:
+        return tile.astype(jnp.float32)
+    return decode(tile, fmt)
+
+
+def _kernel(a_ref, b_ref, scale_ref, o_ref, acc_ref, *, fmt_a, fmt_b):
     """One (i, j, k) grid step: acc += A_ik @ B_kj (fp32), write on last k."""
     k = pl.program_id(2)
 
@@ -52,8 +61,8 @@ def _kernel(a_ref, b_ref, scale_ref, o_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # expanding multiply: decode the minifloat tiles into the wide datapath
-    a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
+    a = _widen(a_ref[...], fmt_a)
+    b = _widen(b_ref[...], fmt_b)
     acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(2) - 1)
@@ -64,15 +73,21 @@ def _kernel(a_ref, b_ref, scale_ref, o_ref, acc_ref):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("out_dtype", "block_m", "block_n", "block_k", "interpret"))
+    static_argnames=("out_dtype", "block_m", "block_n", "block_k", "fmt_a",
+                     "fmt_b", "interpret"))
 def exsdotp_gemm_pallas(a: jax.Array, b: jax.Array, scale: jax.Array,
                         *, out_dtype=jnp.float32,
                         block_m: int = 128, block_n: int = 128,
-                        block_k: int = 512, interpret: bool = False) -> jax.Array:
+                        block_k: int = 512, fmt_a=None, fmt_b=None,
+                        interpret: bool = False) -> jax.Array:
     """C[M,N] = downcast(scale * sum_k A[M,K] B[K,N]) with fp32 accumulation.
 
     ``a``/``b`` may be any narrow dtype XLA can upcast (float8_e5m2,
-    float8_e4m3, float16, bfloat16). ``scale`` is a (1,1) f32 dequant factor
+    float8_e4m3, float16, bfloat16), or ``uint8`` bit patterns of the
+    minifloat format named by ``fmt_a``/``fmt_b`` — how ``ops`` ships
+    IEEE ``float8_e4m3``, which Mosaic cannot load on v5e (it loads
+    ``float8_e4m3fn``/``float8_e5m2``, whose value sets differ from
+    fp8alt's).  ``scale`` is a (1,1) f32 dequant factor
     (product of the per-tensor quantization scales), fused into the final
     write — the paper's ExSdotp structure (DESIGN.md §2): multiply
     narrow, accumulate f32 across the K grid, round once.
@@ -89,7 +104,7 @@ def exsdotp_gemm_pallas(a: jax.Array, b: jax.Array, scale: jax.Array,
         (m, n, k), (block_m, block_n, block_k))
     grid = (m // block_m, n // block_n, k // block_k)
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, fmt_a=fmt_a, fmt_b=fmt_b),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
@@ -100,7 +115,7 @@ def exsdotp_gemm_pallas(a: jax.Array, b: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, jnp.asarray(scale, jnp.float32).reshape(1, 1))

@@ -14,7 +14,7 @@ Two kernels share one online-softmax core (``_sweep_body``):
   §11): k/v enter the kernel as *packed* codec payloads (uint8 lanes at
   ``width/8`` bytes per element) plus E8M0 group-scale codes over the
   head dimension, and are unpacked + decoded in-register
-  (``codec.decode_lanes(...) * e8m0_decode(...)``) right before the
+  (``codec.decode_tile(...) * e8m0_decode(...)``) right before the
   q·kᵀ and p·v dots — the same fold point as ``mx_gemm_packed_pallas``.
   E8M0 scales are exact powers of two, so folding the dequant into the
   decoded operands is bit-identical to rescaling partial products at
@@ -50,11 +50,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import e8m0_decode
 from .codec import get_codec
-from ._compat import CompilerParams
 
 __all__ = ["flash_attention_pallas", "mx_flash_attention_pallas"]
 
 NEG_INF = -1e30
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _sweep_body(q, k, v, m_ref, l_ref, acc_ref, *, iq, kk, causal, scale,
@@ -87,8 +87,12 @@ def _sweep_body(q, k, v, m_ref, l_ref, acc_ref, *, iq, kk, causal, scale,
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    # HIGHEST: p is f32 relative to this tile's running max, which a
+    # default (one bf16 pass) TPU dot rounds to bf16; the references
+    # use the row max, so a row spanning several KV tiles would round
+    # otherwise there.  q·k needs no pin: its operands are exact in bf16.
     acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p, v, preferred_element_type=jnp.float32, precision=_F32)
     m_ref[...] = m_new
 
 
@@ -97,14 +101,13 @@ def _kernel(q_ref, *refs, load_kv, causal, scale, block_q, block_k,
     """Shared kernel shell: init / carry-skip / sweep / retire.
 
     ``load_kv(refs)`` returns ``(loader, base, rest)`` — the only point
-    the carrier, packed, and decode variants differ.  ``loader(kk,
-    limit)`` yields the decoded f32 (k, v) tiles for KV-tile ``kk``
-    (zeroing key slots at index >= ``limit`` when one is given — the
-    decode kernels' structural exclusion of garbage cache slots beyond
-    the live length, so stale poison in freed pages can't leak through
-    ``0·NaN``).  ``base`` (None for train/prefill) is the per-sequence
-    absolute-position offset; with it, q's S rows cover cache slots
-    ``base..base+S-1`` and the live KV prefix is ``limit = base + S``.
+    the carrier, packed, and decode variants differ.  ``loader(kk)``
+    yields the decoded f32 (k, v) tiles for KV-tile ``kk`` (the decode
+    kernels zero key slots beyond the live prefix ``base + S`` — their
+    structural exclusion of garbage cache slots, so stale poison in
+    freed pages can't leak through ``0·NaN``).  ``base`` (None for
+    train/prefill) is the per-sequence absolute-position offset; with
+    it, q's S rows cover cache slots ``base..base+S-1``.
     """
     loader, base, refs = load_kv(refs)
     if debug_visited:
@@ -114,7 +117,6 @@ def _kernel(q_ref, *refs, load_kv, causal, scale, block_q, block_k,
         o_ref, vis_ref = refs[0], None
         m_ref, l_ref, acc_ref = refs[1:]
     iq, kk = pl.program_id(1), pl.program_id(2)
-    limit = None if base is None else base + pl.num_programs(1) * block_q
 
     @pl.when(kk == 0)
     def _init():
@@ -127,7 +129,7 @@ def _kernel(q_ref, *refs, load_kv, causal, scale, block_q, block_k,
 
     def _update():
         q = q_ref[0].astype(jnp.float32)                # [bq, hd]
-        k, v = loader(kk, limit)
+        k, v = loader(kk)
         _sweep_body(q, k, v, m_ref, l_ref, acc_ref,
                     iq=iq, kk=kk, causal=causal, scale=scale,
                     block_q=block_q, block_k=block_k, base=base)
@@ -180,7 +182,7 @@ def _call(kern, q, operands, operand_specs, *, block_q, block_k, t,
             pltpu.VMEM((block_q, 1), jnp.float32),      # running sum
             pltpu.VMEM((block_q, hd), jnp.float32),     # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, *operands)
@@ -219,7 +221,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
     def load_kv(refs):
         k_ref, v_ref = refs[0], refs[1]
 
-        def loader(kk, limit):
+        def loader(kk):
             return (k_ref[0].astype(jnp.float32),
                     v_ref[0].astype(jnp.float32))
 
@@ -290,9 +292,9 @@ def mx_flash_attention_pallas(q, kp, ks8, vp, vs8, *, mx_k, mx_v=None,
     def load_kv(refs):
         kp_ref, ks_ref, vp_ref, vs_ref = refs[:4]
 
-        def loader(kk, limit):
-            return (ck.decode_lanes(kp_ref[0]) * e8m0_decode(ks_ref[0]),
-                    cv.decode_lanes(vp_ref[0]) * e8m0_decode(vs_ref[0]))
+        def loader(kk):
+            return (ck.decode_tile(kp_ref[0]) * e8m0_decode(ks_ref[0]),
+                    cv.decode_tile(vp_ref[0]) * e8m0_decode(vs_ref[0]))
 
         return loader, None, refs[4:]
 

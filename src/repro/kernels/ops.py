@@ -12,12 +12,12 @@ up to block multiples (zero padding is exact for GEMM and for amax).
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
 import jax.numpy as jnp
-
-import math
+import ml_dtypes
 
 from ..core.formats import decode, e8m0_decode, e8m0_encode, encode, \
     get_mx_format
@@ -93,13 +93,22 @@ def exsdotp_gemm(a: jax.Array, b: jax.Array, scale=1.0, *,
     m, k = a.shape
     _, n = b.shape
     bm, bn, bk = blocks or default_blocks(m, n, k, a.dtype.itemsize)
-    a = _pad2(a, bm, bk)
-    b = _pad2(b, bk, bn)
+    a, fmt_a = _kernel_operand(_pad2(a, bm, bk))
+    b, fmt_b = _kernel_operand(_pad2(b, bk, bn))
     out = exsdotp_gemm_pallas(
         a, b, jnp.asarray(scale, jnp.float32).reshape(1, 1),
         out_dtype=out_dtype, block_m=bm, block_n=bn, block_k=bk,
-        interpret=(impl == "pallas_interpret"))
+        fmt_a=fmt_a, fmt_b=fmt_b, interpret=(impl == "pallas_interpret"))
     return out[:m, :n]
+
+
+def _kernel_operand(x: jax.Array):
+    """IEEE ``float8_e4m3`` (fp8alt) enters Pallas as its ``uint8`` bit
+    patterns plus the format name, and is decoded in-register: Mosaic
+    cannot load that dtype on v5e.  Other dtypes pass through."""
+    if x.dtype == jnp.dtype(ml_dtypes.float8_e4m3):
+        return jax.lax.bitcast_convert_type(x, jnp.uint8), "fp8alt"
+    return x, None
 
 
 def blockscale_blocks(m: int, n: int, k: int,
@@ -478,6 +487,15 @@ def decode_attention_blocks(s: int, t: int) -> tuple[int, int]:
     return pick(s, 1), pick(t, 8)
 
 
+def _compiled_q_tile(s: int, bq: int, impl: str) -> int:
+    """Compiled TPU takes a q tile of the whole S=1 row or a sublane
+    8-multiple; the decode wrappers pad S up to it (a prompt of 17 rows
+    runs as 24)."""
+    if impl == "pallas" and s > 1 and bq % 8:
+        return 8
+    return bq
+
+
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      lens: jax.Array, *, block_q=None, block_k=None,
                      impl: str = "auto", tiles=None) -> jax.Array:
@@ -501,6 +519,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             impl=impl, sweep=_tune_sweep_enabled())
     else:
         bq, bk = decode_attention_blocks(q.shape[1], k.shape[1])
+    bq = _compiled_q_tile(q.shape[1], bq, impl)
     return decode_attention_pallas(
         q, k, v, lens, block_q=block_q or bq, block_k=block_k or bk,
         interpret=(impl == "pallas_interpret"))
@@ -542,6 +561,7 @@ def mx_decode_attention_packed(q: jax.Array, kp: jax.Array, ks8: jax.Array,
             sweep=_tune_sweep_enabled())
     else:
         bq, bk = decode_attention_blocks(q.shape[1], kp.shape[1])
+    bq = _compiled_q_tile(q.shape[1], bq, impl)
     return mx_decode_attention_pallas(
         q, kp, ks8, vp, vs8, lens, mx_k=mx_k, mx_v=mx_v,
         block_q=block_q or bq, block_k=block_k or bk,

@@ -27,11 +27,14 @@ FP6 lane (4 codes / 3 bytes)::
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["pack_codes_np", "unpack_codes_np", "pack_codes", "unpack_codes",
+           "pack_codes_tile", "unpack_codes_tile",
            "pack4_np", "unpack4_np", "pack6_np", "unpack6_np",
            "pack4", "unpack4", "pack6", "unpack6", "packed_length"]
 
@@ -147,3 +150,79 @@ def unpack_codes(packed: jax.Array, width: int) -> jax.Array:
     if width == 8:
         return packed.astype(jnp.uint8)
     return {4: unpack4, 6: unpack6}[width](packed)
+
+
+# ------------------------------------------------ in-kernel (Mosaic) ------
+# Compiled TPU Pallas cannot gather lanes with a stride (``c[..., 0::2]``)
+# nor reshape a tile's lane axis, which is what the jnp mirrors above
+# lower to.  Inside kernels the same little-endian layout is produced
+# by 0/1 selection matmuls on the MXU instead: every code splits into
+# the bits that land in its first byte and the bits that spill into the
+# next, and a matmul routes each piece to its byte.  Operands are small
+# integers (< 256, exact in bf16) and each output sums disjoint bit
+# fields, so the f32 accumulation is exact — bit-identical to the
+# strided versions.  Tiles are processed in runs of ``lane_unit`` codes
+# (whole 128-byte lane tiles), so the selection matrices stay small.
+
+def _tile_run(n: int, width: int) -> int:
+    unit = 8 * 128 // math.gcd(width, 8)   # codes per 128-byte multiple
+    return unit if n % unit == 0 else n
+
+
+def _field_shift(shape, width: int, axis: int):
+    """Bit offset, within its first byte, of the code on each lane."""
+    k = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    return (k * width) % 8
+
+
+def _select(shape, width: int, code_axis: int, spill: int):
+    """0/1 bf16 matrix routing code ``k`` to byte ``(k·w)//8 + spill``."""
+    k = jax.lax.broadcasted_iota(jnp.int32, shape, code_axis)
+    j = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - code_axis)
+    return (j == (k * width) // 8 + spill).astype(jnp.bfloat16)
+
+
+def _route(x, sel):
+    y = jnp.dot(x.astype(jnp.float32).astype(jnp.bfloat16), sel,
+                preferred_element_type=jnp.float32)
+    return y.astype(jnp.int32)
+
+
+def pack_codes_tile(codes: jax.Array, width: int) -> jax.Array:
+    """``pack_codes`` for a 2-D kernel tile ``[rows, K]`` (uint8 codes →
+    ``[rows, K·w/8]`` bytes), built from shifts and selection matmuls."""
+    if width == 8:
+        return codes.astype(jnp.uint8)
+    rows, n = codes.shape
+    run = _tile_run(n, width)
+    nb = run * width // 8
+    sh = _field_shift((rows, run), width, 1)
+    lo = _select((run, nb), width, 0, 0)
+    hi = _select((run, nb), width, 0, 1)
+    out = []
+    for i in range(n // run):
+        c = codes[:, i * run:(i + 1) * run].astype(jnp.int32)
+        b = _route((c << sh) & 0xFF, lo) + _route(c >> (8 - sh), hi)
+        out.append(b.astype(jnp.uint32).astype(jnp.uint8))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=-1)
+
+
+def unpack_codes_tile(packed: jax.Array, width: int) -> jax.Array:
+    """``unpack_codes`` for a 2-D kernel tile ``[rows, B]`` (bytes →
+    ``[rows, 8B/w]`` uint8 codes): each code gathers its first byte and
+    the next by selection matmuls, then shifts and masks."""
+    if width == 8:
+        return packed.astype(jnp.uint8)
+    rows, nb = packed.shape
+    run = _tile_run(nb * 8 // width, width)
+    rb = run * width // 8
+    sh = _field_shift((rows, run), width, 1)
+    lo = _select((rb, run), width, 1, 0)
+    hi = _select((rb, run), width, 1, 1)
+    out = []
+    for i in range(nb // rb):
+        b = packed[:, i * rb:(i + 1) * rb].astype(jnp.int32)
+        c = ((_route(b, lo) >> sh) | (_route(b, hi) << (8 - sh))) & (
+            (1 << width) - 1)
+        out.append(c.astype(jnp.uint32).astype(jnp.uint8))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=-1)

@@ -25,8 +25,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import _quantize_f32, e8m0_encode, get_mx_format
-from ..core.scaling import compute_group_scales, expand_group_scales
-from ._compat import CompilerParams
+from ..core.scaling import group_scales_from_amax, pow2_reciprocal
 from .codec import get_codec
 
 __all__ = ["quant_blockwise_pallas", "mx_quant_pallas",
@@ -88,7 +87,7 @@ def quant_blockwise_pallas(x: jax.Array, *, q_dtype,
             jax.ShapeDtypeStruct((mp, np_), q_dtype),
             jax.ShapeDtypeStruct(grid, jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x)
@@ -97,12 +96,46 @@ def quant_blockwise_pallas(x: jax.Array, *, q_dtype,
 
 # --------------------------------------------------------------- MX path --
 
+def _group_amax_lanes(y: jax.Array, group: int) -> jax.Array:
+    """Max of ``y [bm, bk]`` over each aligned 1×``group`` lane strip,
+    broadcast back to every lane of the strip.
+
+    An XOR butterfly of lane rolls: after step ``sh`` each lane holds
+    the max over the ``2·sh`` lanes sharing its high index bits.  A
+    rolled lane iota picks, per lane, whichever roll brought its
+    partner ``lane ^ sh`` (so the roll's direction convention does not
+    matter).  Mosaic cannot reshape a tile into ``[bm, bk/group,
+    group]``; this keeps the reduction on native ``[bm, bk]`` vregs and
+    yields the scales already at element resolution.
+    """
+    n = y.shape[-1]
+    ax = y.ndim - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, y.shape, ax)
+    sh = 1
+    while sh < group:
+        fwd, bwd = pltpu.roll(y, sh, ax), pltpu.roll(y, n - sh, ax)
+        src = pltpu.roll(lane, sh, ax)
+        y = jnp.maximum(y, jnp.where(src == (lane ^ sh), fwd, bwd))
+        sh *= 2
+    return y
+
+
+def _group_scales_lanes(x: jax.Array, group: int, elem_max: float):
+    """Element-resolution E8M0 scales of ``x [bm, bk]`` (in-kernel
+    ``expand_group_scales(compute_group_scales(x))``, bit-identical:
+    max is exact in any order, and the scale formula is shared)."""
+    assert group & (group - 1) == 0, group
+    return group_scales_from_amax(_group_amax_lanes(jnp.abs(x), group),
+                                  elem_max)
+
+
 def _mx_kernel(x_ref, q_ref, se_ref, *, fmt, group: int):
     """Fused MX group quantize for one (bm, bk) tile.
 
-    Per 1×group strip: amax -> E8M0 pow2 scale (non-finite -> NaN scale,
-    zero -> neutral 1, via ``compute_group_scales`` — the single source
-    of the E8M0 formula) -> exact pow2 divide -> value-space element
+    Per 1×group strip: amax (``_group_amax_lanes``) -> E8M0 pow2 scale
+    (non-finite -> NaN scale, zero -> neutral 1, via
+    ``group_scales_from_amax`` — the single source of the E8M0 formula)
+    -> exact pow2 divide -> value-space element
     cast (`_quantize_f32`, bit-identical to a native cast where one
     exists).  The scale output is written at *element resolution*
     (``se[bm, bk]``): a compact ``(bm, bk//32)`` tile would put a
@@ -111,10 +144,8 @@ def _mx_kernel(x_ref, q_ref, se_ref, *, fmt, group: int):
     compacts with a strided slice instead.
     """
     x = x_ref[...].astype(jnp.float32)
-    bm, bk = x.shape
-    s = compute_group_scales(x, group, fmt.max_normal)
-    se = expand_group_scales(s, group).reshape(bm, bk)
-    q_ref[...] = _quantize_f32(x / se, fmt)
+    se = _group_scales_lanes(x, group, fmt.max_normal)
+    q_ref[...] = _quantize_f32(x * pow2_reciprocal(se), fmt)
     se_ref[...] = se
 
 
@@ -155,7 +186,7 @@ def mx_quant_pallas(x: jax.Array, *, mx, block_m: int = 128,
             jax.ShapeDtypeStruct((m, k), jnp.float32),
             jax.ShapeDtypeStruct((m, k), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x)
@@ -170,7 +201,7 @@ def _mx_packed_kernel(x_ref, p_ref, s8_ref, *, codec, group: int):
 
     Same group amax → E8M0 pow2 scale → exact pow2 divide pipeline as
     ``_mx_kernel``, but the element cast lands straight in *packed*
-    uint8 storage: ``codec.encode_lanes`` quantizes, extracts the bit
+    uint8 storage: ``codec.encode_tile`` quantizes, extracts the bit
     patterns and packs them into dense lanes in-register, so the
     payload leaves VMEM at ``width/8`` bytes per element — no byte- or
     f32-wide quantized intermediate ever reaches HBM.  Scales are
@@ -182,11 +213,9 @@ def _mx_packed_kernel(x_ref, p_ref, s8_ref, *, codec, group: int):
     — the §8 poison convention, byte-level.
     """
     x = x_ref[...].astype(jnp.float32)
-    bm, bk = x.shape
-    s = compute_group_scales(x, group, codec.fmt.max_normal)
-    se = expand_group_scales(s, group).reshape(bm, bk)
+    se = _group_scales_lanes(x, group, codec.fmt.max_normal)
     s8_ref[...] = e8m0_encode(se)
-    p_ref[...] = codec.encode_lanes(x / se)
+    p_ref[...] = codec.encode_tile(x * pow2_reciprocal(se))
 
 
 @functools.partial(
@@ -229,7 +258,7 @@ def mx_quant_packed_pallas(x: jax.Array, *, mx, block_m: int = 128,
             jax.ShapeDtypeStruct((m, codec.packed_cols(k)), jnp.uint8),
             jax.ShapeDtypeStruct((m, k), jnp.uint8),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x)

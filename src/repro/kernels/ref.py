@@ -20,6 +20,10 @@ __all__ = ["exsdotp_gemm_ref", "quant_blockwise_ref", "blockscale_gemm_ref",
            "mx_decode_attention_ref", "compressed_mean_mx_ref",
            "mx_dispatch_wire_ref"]
 
+#: the attention p·v dot keeps its f32 probabilities (a default TPU dot
+#: rounds them to bf16): the precision the Pallas kernels pin
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def exsdotp_gemm_ref(a: jax.Array, b: jax.Array, scale=1.0,
                      *, out_dtype=jnp.float32) -> jax.Array:
@@ -132,8 +136,8 @@ def flash_attention_ref(q, k, v, *, causal=True):
         mask = jnp.arange(tk)[None, :] <= jnp.arange(sq)[:, None]
         s = jnp.where(mask[None], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", w,
-                      v.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum("bqk,bkd->bqd", w, v.astype(jnp.float32),
+                      precision=_F32).astype(q.dtype)
 
 
 def decode_attention_ref(q, k, v, lens, *, neg=-1e30):
@@ -162,7 +166,7 @@ def decode_attention_ref(q, k, v, lens, *, neg=-1e30):
     m = jnp.max(sc, axis=-1, keepdims=True)
     p = jnp.exp(sc - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    acc = jnp.einsum("bqk,bkd->bqd", p, vf)
+    acc = jnp.einsum("bqk,bkd->bqd", p, vf, precision=_F32)
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 

@@ -1,9 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST precede every other import: jax locks the device
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST precede every other import: jax locks the device
 # count at first init, and the dry-run needs 512 placeholder host devices
-# to build the production meshes. (Only this entry point does this — tests
-# and benches see the real single CPU device.)
+# to build the production meshes.  The CPU pin keeps this process and the
+# per-cell children it spawns (which inherit it) off any attached chip.
+# (Only this entry point does this — tests and benches see the real
+# single CPU device.)
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

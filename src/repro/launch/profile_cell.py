@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # placeholder devices, never the chip
 
 """Dry-run profiler: compile one cell and attribute collective bytes, dot
 FLOPs and large buffers to source ops — the measurement half of the

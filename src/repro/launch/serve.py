@@ -22,6 +22,7 @@ from ..configs import get_arch
 from ..core.policy import POLICIES
 from ..models import build_model
 from ..serve.decode import generate
+from .compile_cache import enable_compile_cache
 from .hlo_analysis import format_serve_cache_footprint
 
 
@@ -37,6 +38,10 @@ def main():
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--page-size", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[launch.serve] device: {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}")
 
     cfg = get_arch(args.arch)
     if args.reduced:

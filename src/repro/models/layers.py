@@ -68,6 +68,11 @@ def proj(x, w, b, policy, rules, impl, kind="plain", quantized=True):
     elif ok and kind == "row":
         y = tp_row_linear(x, w, policy, rules)
     else:
+        if (rules is not None and rules.mesh is not None
+                and ops.resolve_impl(impl) == "pallas"):
+            # GSPMD partitions this GEMM, and compiled Mosaic kernels
+            # cannot be partitioned automatically: run it as XLA dots
+            impl = "xla"
         return linear(x, w, b, policy=policy, impl=impl, quantized=quantized)
     if b is not None:
         y = y + b.astype(y.dtype)

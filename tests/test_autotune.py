@@ -105,20 +105,25 @@ def test_stale_entry_outside_candidates_resweeps(tune_dir):
     assert res.source == "swept" and res.tiles == (8,) and calls
 
 
-def test_failing_candidates_skipped_all_fail_defaults(tune_dir):
+def test_failing_candidates_skipped_all_fail_defaults(tune_dir, caplog):
+    """A refused candidate is skipped *and logged with its error*; when
+    every candidate fails the sweep raises instead of returning an
+    untested tile."""
     def bench(tl):
         if tl == (8,):
             raise RuntimeError("illegal tile")
         return float(sum(tl))
 
-    res = autotune.autotune("toy", "k2", [(8,), (16,)], bench)
+    with caplog.at_level("WARNING", logger=autotune.__name__):
+        res = autotune.autotune("toy", "k2", [(8,), (16,)], bench)
     assert res.source == "swept" and res.tiles == (16,)
+    assert "(8,)" in caplog.text and "illegal tile" in caplog.text
 
     def bomb(tl):
         raise RuntimeError("no candidate runs")
 
-    res = autotune.autotune("toy", "k3", [(8,), (16,)], bomb)
-    assert res.source == "default" and res.tiles == (8,)
+    with pytest.raises(RuntimeError, match="every candidate failed"):
+        autotune.autotune("toy", "k3", [(8,), (16,)], bomb)
     assert autotune.peek("toy", "k3") is None    # failures never persist
 
 

@@ -208,3 +208,27 @@ def test_packed_kernel_checks_payload_shapes():
         mx_decode_attention_pallas(q, kp, ks8, kp, ks8, lens,
                                    mx_k="mxfp8e4m3", block_q=4,
                                    block_k=32, interpret=True)
+
+
+@pytest.mark.parametrize("name", [None, "mxfp8e4m3"])
+def test_q_rows_padded_to_tile(name):
+    """A q tile that does not divide S (compiled TPU takes 8-row tiles
+    for a 7-row prefill): the wrapper pads q's rows, and the garbage
+    limit stays at the true ``lens + S`` — bitwise the same as the
+    dividing tile, NaN trash just past the live prefix included."""
+    rng = np.random.default_rng(23)
+    q, k, v, lens = fuzz.exact_decode_operands(rng, 2, 7, 64, 64, [3, 40])
+    qj, lj = jnp.asarray(q), jnp.asarray(lens)
+    if name is None:
+        run = lambda bq: decode_attention_pallas(
+            qj, jnp.asarray(k), jnp.asarray(v), lj, block_q=bq,
+            block_k=32, interpret=True)
+    else:
+        kp, ks8, vp, vs8 = _quantized(k, v, name)
+        run = lambda bq: mx_decode_attention_pallas(
+            qj, kp, ks8, vp, vs8, lj, mx_k=name, block_q=bq, block_k=32,
+            interpret=True)
+    padded, exact = np.asarray(run(8)), np.asarray(run(1))
+    assert padded.shape == q.shape
+    assert np.isfinite(padded).all()
+    np.testing.assert_array_equal(padded, exact)

@@ -183,3 +183,17 @@ def test_mx_gemm_packed_mixed_formats_and_poison():
                              mx_b="mxfp8e5m2")
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
     assert np.isnan(np.asarray(got)[1]).all()
+
+
+@pytest.mark.parametrize("width", [4, 6])
+@pytest.mark.parametrize("k", [64, 1024, 2048], ids=["one_run", "runs", "runs2"])
+def test_tile_pack_matches_strided(width, k):
+    """The in-kernel (selection-matmul) pack/unpack produce the same
+    bytes and codes as the strided jnp layout, in one run or several."""
+    rng = np.random.default_rng(width * k)
+    codes = jnp.asarray(rng.integers(0, 1 << width, (8, k)), jnp.uint8)
+    packed = P.pack_codes(codes, width)
+    np.testing.assert_array_equal(np.asarray(P.pack_codes_tile(codes, width)),
+                                  np.asarray(packed))
+    np.testing.assert_array_equal(
+        np.asarray(P.unpack_codes_tile(packed, width)), np.asarray(codes))

@@ -185,6 +185,24 @@ def test_compressed_psum_matches_mean_with_error_feedback():
     assert rel < 0.01
 
 
+def test_proj_under_mesh_runs_gspmd_gemms_as_xla():
+    """A projection GSPMD partitions (no explicit TP wire) never reaches
+    a compiled Mosaic kernel, which cannot be partitioned automatically:
+    under a mesh, impl='pallas' runs it as the XLA GEMM (compiled Pallas
+    would not even run on this CPU backend)."""
+    from repro.core.linear import linear
+    from repro.models.layers import proj
+    from repro.parallel.sharding import make_rules
+    rules = make_rules(make_mesh((1, 1), ("data", "model")))
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(0, 1, (2, 8, 64)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(0, 1, (64, 128)), jnp.bfloat16)
+    got = proj(x, w, None, "hfp8", rules, "pallas", kind="col")
+    want = linear(x, w, policy="hfp8", impl="xla")
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
 # -------------------------------------------------------------- serving ---
 
 def test_generate_greedy():
