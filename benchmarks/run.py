@@ -1,6 +1,7 @@
 """Benchmark harness — one entry per paper table/figure + the roofline
-table from the dry-run artifacts. Prints ``name,us_per_call,derived`` CSV
-for timed sections and structured CSV for modeled/accuracy sections.
+table from the dry-run artifacts. Prints structured CSV for the modeled
+and accuracy sections and JSON for the byte counts; nothing here times
+a kernel (device time comes from the chip benchmark, ``bench/``).
 
 Covers: Table II / Fig. 8 (table2_gemm), Table IV (table4_accuracy),
 Fig. 7a (fig7_resources), plus the beyond-paper block-scaling sweep
@@ -12,74 +13,6 @@ Run:
 from __future__ import annotations
 
 import sys
-import time
-
-import numpy as np
-
-
-def _time_us(fn, *args, warmup=2, iters=10):
-    """Median wall-clock microseconds of ``fn(*args)``, each iteration
-    synchronized with ``block_until_ready``.
-
-    Timing the loop without per-iteration sync measures dispatch (jax
-    enqueues asynchronously and the queue drains after the clock stops),
-    and the mean lets one scheduler hiccup skew the number — the
-    ``autotune.time_us_median`` convention (EXPERIMENTS.md
-    §Conventions).
-    """
-    import jax
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        ts.append((time.perf_counter() - t0) * 1e6)
-    return float(np.median(ts))
-
-
-def bench_kernels(quick=False):
-    """Wall-clock of the expanding-GEMM primitive (CPU, XLA path) vs a
-    plain f32 GEMM — the fp8-storage memory win shows up even on CPU."""
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels import ops
-
-    print("# kernel microbench (CPU wall-clock; XLA path)")
-    print("name,us_per_call,derived")
-    rng = np.random.default_rng(0)
-    sizes = [(256, 256, 256)] if quick else [(256, 256, 256),
-                                             (512, 512, 512),
-                                             (1024, 1024, 1024)]
-    for m, k, n in sizes:
-        a8 = jnp.asarray(rng.normal(0, 1, (m, k)), jnp.float8_e4m3)
-        b8 = jnp.asarray(rng.normal(0, 1, (k, n)), jnp.float8_e5m2)
-        af = a8.astype(jnp.float32)
-        bf = b8.astype(jnp.float32)
-        g8 = jax.jit(lambda a, b: ops.exsdotp_gemm(a, b, 1.0, impl="xla"))
-        gf = jax.jit(lambda a, b: (a @ b))
-        t8 = _time_us(g8, a8, b8)
-        tf = _time_us(gf, af, bf)
-        gflops = 2 * m * n * k / 1e9
-        print(f"exsdotp_gemm_xla_{m}x{k}x{n},{t8:.1f},"
-              f"{gflops / (t8 / 1e6):.1f}GFLOP/s")
-        print(f"fp32_gemm_{m}x{k}x{n},{tf:.1f},"
-              f"{gflops / (tf / 1e6):.1f}GFLOP/s")
-        # fused blockwise quantization (memory-roofline primitive)
-        x = jnp.asarray(rng.normal(0, 1, (m, k)), jnp.float32)
-        q = jax.jit(lambda v: ops.quantize_blockwise(v, jnp.float8_e4m3,
-                                                     impl="xla"))
-        tq = _time_us(q, x)
-        print(f"quant_blockwise_{m}x{k},{tq:.1f},"
-              f"{m * k * 4 / (tq / 1e6) / 1e9:.1f}GB/s_read")
-    # Pallas interpret-mode timing (Python-level emulation — correctness
-    # path only; absolute numbers are not meaningful, recorded for trend)
-    a8 = jnp.asarray(rng.normal(0, 1, (64, 64)), jnp.float8_e4m3)
-    b8 = jnp.asarray(rng.normal(0, 1, (64, 64)), jnp.float8_e5m2)
-    tp = _time_us(lambda a, b: ops.exsdotp_gemm(
-        a, b, 1.0, impl="pallas_interpret", blocks=(32, 32, 32)), a8, b8,
-        warmup=1, iters=3)
-    print(f"exsdotp_gemm_pallas_interpret_64,{tp:.1f},emulation")
 
 
 def main() -> None:
@@ -98,8 +31,6 @@ def main() -> None:
     from benchmarks import fig7_resources
     fig7_resources.main()
     print("=" * 72)
-    bench_kernels(quick)
-    print("=" * 72)
     print("## Block-scaled vs per-tensor GEMM (beyond-paper; outlier sweep)")
     from benchmarks import blockscale_gemm
     blockscale_gemm.accuracy_sweep(quick)
@@ -110,15 +41,10 @@ def main() -> None:
     from benchmarks import mx_packed_sweep
     mx_packed_sweep.main(quick)
     print("=" * 72)
-    print("## Packed GEMM vs the machine's own roofline (§14)")
-    import json as _json
-    from benchmarks import gemm_sweep
-    print(_json.dumps(gemm_sweep.measure(quick), indent=2, sort_keys=True))
-    print("=" * 72)
-    print("## Serving: paged-cache bytes/seq + decode tok/s per policy (§12)")
+    print("## Serving: paged-cache bytes/seq per policy (§12)")
     import json as _json
     from benchmarks import serve_sweep
-    print(_json.dumps(serve_sweep.measure(quick), indent=2, sort_keys=True))
+    print(_json.dumps(serve_sweep.measure(), indent=2, sort_keys=True))
     print("=" * 72)
     print("## Wire bytes per policy across the explicit TP wire (§9)")
     import jax

@@ -1,17 +1,14 @@
-"""Serving sweep: decode throughput + KV-cache HBM bytes per sequence
-across cache policies (DESIGN.md §12).
+"""Serving sweep: KV-cache HBM bytes per sequence across cache
+policies (DESIGN.md §12).
 
 For each serving policy (``bf16`` carrier pages, ``mxfp8``/``mxfp6``/
 ``mxfp4`` packed payload + E8M0 pages) the paged cache is built for a
-small dense config and a batch of requests runs through the
-continuous batcher (``serve.scheduler``); reported per policy:
+small dense config; reported per policy:
 
 * ``cache_bytes_per_seq`` — the HBM bytes one sequence's page-pool
   share pins across the layer stack (trash page excluded), measured
   from the actual cache arrays AND cross-checked against the analytic
   ``serve.kv_cache.paged_kv_bytes_per_seq`` — they must agree exactly;
-* ``tok_s`` per batch size — host wall-clock through the scheduler
-  (CPU/XLA here; informational, not gated — wall time is noisy);
 * ``ratios`` — packed-vs-bf16 cache compression.  ``mxfp4`` must hold
   >= 2.5x (the paper-level win the packed pipeline promises; the
   layout arithmetic gives 2.0 / 0.53125 ≈ 3.76x).
@@ -22,14 +19,13 @@ committed baseline (``benchmarks/baselines/serve.json``) or the mxfp4
 compression ratio drops below 2.5x — mirroring the wire-bytes gate.
 
 Run:
-    PYTHONPATH=src python -m benchmarks.serve_sweep [--quick]
+    PYTHONPATH=src python -m benchmarks.serve_sweep
         [--out BENCH_serve.json] [--check benchmarks/baselines/serve.json]
 """
 from __future__ import annotations
 
 import json
 import sys
-import time
 
 POLICIES = ("bf16", "mxfp8", "mxfp6", "mxfp4")
 MIN_MXFP4_RATIO = 2.5
@@ -54,30 +50,19 @@ def _pool_bytes_per_seq(cache, mp):
     return total
 
 
-def measure(quick=False):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
+def measure():
     from repro.models import build_model
     from repro.serve.kv_cache import (max_pages, paged_kv_applicable,
                                       paged_kv_bytes_per_seq)
-    from repro.serve.scheduler import ContinuousBatcher, ServeRequest
 
     max_len, page_size = 64, 16
-    prompt_len = 6
-    new_tokens = 4 if quick else 8
-    batches = (2,) if quick else (2, 4)
     mp = max_pages(max_len, page_size)
     report = {"shape": {"max_len": max_len, "page_size": page_size,
-                        "prompt_len": prompt_len, "new_tokens": new_tokens,
                         "config": "dense L=2 d=64 H=4 KV=2 hd=32"},
               "policies": {}}
-    rng = np.random.default_rng(0)
     for pname in POLICIES:
         cfg = _cfg(pname)
         model = build_model(cfg)
-        params = model.init(jax.random.key(0))
         from repro.core.policy import get_policy
         pol = get_policy(pname)
         cache = model.init_cache(2, max_len, paged=True,
@@ -86,23 +71,10 @@ def measure(quick=False):
         analytic = paged_kv_bytes_per_seq(cfg, pol, max_len,
                                           page_size=page_size)
         assert measured == analytic, (pname, measured, analytic)
-        rec = {"packed": paged_kv_applicable(cfg, pol),
-               "cache_format": pol.mx_kv_cache_name or "carrier-bf16",
-               "cache_bytes_per_seq": measured,
-               "tok_s": {}}
-        for batch in batches:
-            reqs = [ServeRequest(i, rng.integers(1, cfg.vocab_size,
-                                                 prompt_len), new_tokens)
-                    for i in range(batch)]
-            cb = ContinuousBatcher(model, params, max_batch=batch,
-                                   max_len=max_len, page_size=page_size,
-                                   impl="auto")
-            t0 = time.perf_counter()
-            out = cb.run(reqs)
-            dt = time.perf_counter() - t0
-            assert len(out) == batch
-            rec["tok_s"][str(batch)] = round(batch * new_tokens / dt, 2)
-        report["policies"][pname] = rec
+        report["policies"][pname] = {
+            "packed": paged_kv_applicable(cfg, pol),
+            "cache_format": pol.mx_kv_cache_name or "carrier-bf16",
+            "cache_bytes_per_seq": measured}
     base = report["policies"]["bf16"]["cache_bytes_per_seq"]
     report["ratios"] = {
         f"{p}_vs_bf16": round(
@@ -144,7 +116,7 @@ def main():
             return args[args.index(name) + 1]
         return default
 
-    report = measure(quick="--quick" in args)
+    report = measure()
     out = opt("--out", "BENCH_serve.json")
     with open(out, "w") as f:
         json.dump(report, f, indent=2, sort_keys=True)

@@ -225,7 +225,7 @@ def serve_phase(cfg, *, prompt_lens=SERVE_PROMPTS, new_tokens=SERVE_NEW,
                            page_size=page_size)
     toks = jnp.zeros((slots,), jnp.int32)
     t0 = time.perf_counter()
-    compiled = cb._step.lower(params, toks, cb.cache).compile()
+    compiled = cb.decode_step.lower(params, toks, cb.cache).compile()
     decode_compile_s = time.perf_counter() - t0
     kernels = require_kernels(compiled, "decode step")
 
@@ -255,7 +255,7 @@ def serve_phase(cfg, *, prompt_lens=SERVE_PROMPTS, new_tokens=SERVE_NEW,
     # is the format's own effect: the same weights under bf16.  The
     # kernels themselves are held to tight tolerances in kernel_checks.
     nxt = [int(out[r.uid][0]) for r in reqs]       # what the batcher sampled
-    got = run(model, params, cb._step, nxt=nxt)
+    got = run(model, params, cb.decode_step, nxt=nxt)
     want = run(model, params, _xla_step(model), nxt=nxt)
     wide_model = build_model(dataclasses.replace(cfg, policy_name="bf16"))
     wide = run(wide_model, params, _xla_step(wide_model), nxt=nxt)

@@ -56,8 +56,8 @@ def _tune_sweep_enabled() -> bool:
     CI) answer from the committed cache or fall back to the static
     heuristic, so they stay deterministic and never burn minutes timing
     interpret-mode kernels.  ``REPRO_TUNE_SWEEP=1`` forces sweeping
-    anywhere (how ``benchmarks/gemm_sweep.py --tune`` populates the
-    committed cache); ``=0`` forbids it even on TPU (DESIGN.md §14).
+    anywhere (how the committed cache is populated); ``=0`` forbids it
+    even on TPU (DESIGN.md §14).
     """
     env = os.environ.get("REPRO_TUNE_SWEEP")
     if env is not None:

@@ -97,6 +97,7 @@ def layer_norm(x, scale, bias, eps):
     return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def apply_norm(x, p, cfg):
     if cfg.norm == "layernorm":
         return layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)
@@ -255,6 +256,7 @@ def _mx_attention_applicable(policy, *, s, t, hd, kv_cache, cross_kv):
     return ops.attention_blocks(s, t) is not None
 
 
+@jax.named_scope("attn")
 def attention(x, p, cfg, policy, *, positions, kv_cache=None, cross_kv=None,
               causal=None, rules=None, impl="auto"):
     """Returns (out [B,S,D], new_kv_cache).
@@ -262,22 +264,29 @@ def attention(x, p, cfg, policy, *, positions, kv_cache=None, cross_kv=None,
     * train/prefill: kv_cache None -> full self-attention over x.
     * decode: kv_cache dict(k, v, idx) -> append and attend to the cache.
     * cross_kv (Bx[T,KV,hd] pair): encoder-decoder cross attention.
+
+    Runs under the ``attn`` scope, its parts under ``repro.scopes.ATTN``.
     """
     policy = get_policy(policy)
     b, s, _ = x.shape
     hd = cfg.head_dim_eff
     causal = cfg.causal if causal is None else causal
 
-    q = proj(x, p["wq"], p.get("bq"), policy, rules, impl, kind="col")
-    q = q.reshape(b, s, cfg.n_heads, hd)
+    with jax.named_scope("qkv"):
+        q = proj(x, p["wq"], p.get("bq"), policy, rules, impl, kind="col")
+        q = q.reshape(b, s, cfg.n_heads, hd)
+        if cross_kv is None:
+            k = proj(x, p["wk"], p.get("bk"), policy, rules, impl,
+                     kind="col")
+            v = proj(x, p["wv"], p.get("bv"), policy, rules, impl,
+                     kind="col")
+            k = k.reshape(b, s, cfg.n_kv_heads, hd)
+            v = v.reshape(b, s, cfg.n_kv_heads, hd)
     if cross_kv is None:
-        k = proj(x, p["wk"], p.get("bk"), policy, rules, impl, kind="col")
-        v = proj(x, p["wv"], p.get("bv"), policy, rules, impl, kind="col")
-        k = k.reshape(b, s, cfg.n_kv_heads, hd)
-        v = v.reshape(b, s, cfg.n_kv_heads, hd)
         if cfg.pos_embed == "rope":
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            with jax.named_scope("rope"):
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k = apply_rope(k, positions, cfg.rope_theta)
     else:
         k, v = cross_kv
         causal = False
@@ -292,17 +301,19 @@ def attention(x, p, cfg, policy, *, positions, kv_cache=None, cross_kv=None,
                                    kv_cache["lens"], cfg=cfg, policy=policy,
                                    impl=impl)
         out = out.reshape(b, s, cfg.n_heads * hd)
-        out = proj(out, p["wo"], None, policy, rules, impl, kind="row")
+        with jax.named_scope("out"):
+            out = proj(out, p["wo"], None, policy, rules, impl, kind="row")
         return out, new_kv
 
     new_cache = None
     kv_valid_len = None
     if kv_cache is not None:
         idx = kv_cache["idx"]
-        k = jax.lax.dynamic_update_slice(kv_cache["k"], k.astype(
-            kv_cache["k"].dtype), (0, idx, 0, 0))
-        v = jax.lax.dynamic_update_slice(kv_cache["v"], v.astype(
-            kv_cache["v"].dtype), (0, idx, 0, 0))
+        with jax.named_scope("kv_write"):
+            k = jax.lax.dynamic_update_slice(kv_cache["k"], k.astype(
+                kv_cache["k"].dtype), (0, idx, 0, 0))
+            v = jax.lax.dynamic_update_slice(kv_cache["v"], v.astype(
+                kv_cache["v"].dtype), (0, idx, 0, 0))
         new_cache = {"k": k, "v": v, "idx": idx + s}
         kv_valid_len = idx + s
         # causal masking still applies via absolute positions (cache slots
@@ -315,26 +326,28 @@ def attention(x, p, cfg, policy, *, positions, kv_cache=None, cross_kv=None,
         v = rules.act(v, "batch", None, "kv_heads" if cfg.n_kv_heads > 1 else None, None)
 
     t = k.shape[1]
-    if _mx_attention_applicable(policy, s=s, t=t, hd=hd, kv_cache=kv_cache,
-                                cross_kv=cross_kv):
-        # GQA repeat stays OUTSIDE the custom_vjp: repeat's own autodiff
-        # sums dk/dv back over the head groups for free.
-        rep = cfg.n_heads // cfg.n_kv_heads
-        kr = jnp.repeat(k, rep, axis=2).transpose(0, 2, 1, 3)
-        vr = jnp.repeat(v, rep, axis=2).transpose(0, 2, 1, 3)
-        qh = q.transpose(0, 2, 1, 3)                     # [B,H,S,hd]
-        h = cfg.n_heads
-        out = _mx_sdpa(qh.reshape(b * h, s, hd),
-                       kr.reshape(b * h, t, hd),
-                       vr.reshape(b * h, t, hd),
-                       policy.mx_attn_name, causal, impl)
-        out = out.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
-    else:
-        out = _sdpa_chunked(q, k, v, causal=causal, q_positions=positions,
-                            kv_valid_len=kv_valid_len, chunk=cfg.attn_q_chunk,
-                            rules=rules)
+    with jax.named_scope("core"):
+        if _mx_attention_applicable(policy, s=s, t=t, hd=hd,
+                                    kv_cache=kv_cache, cross_kv=cross_kv):
+            # GQA repeat stays OUTSIDE the custom_vjp: repeat's own
+            # autodiff sums dk/dv back over the head groups for free.
+            rep = cfg.n_heads // cfg.n_kv_heads
+            kr = jnp.repeat(k, rep, axis=2).transpose(0, 2, 1, 3)
+            vr = jnp.repeat(v, rep, axis=2).transpose(0, 2, 1, 3)
+            qh = q.transpose(0, 2, 1, 3)                     # [B,H,S,hd]
+            h = cfg.n_heads
+            out = _mx_sdpa(qh.reshape(b * h, s, hd),
+                           kr.reshape(b * h, t, hd),
+                           vr.reshape(b * h, t, hd),
+                           policy.mx_attn_name, causal, impl)
+            out = out.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
+        else:
+            out = _sdpa_chunked(q, k, v, causal=causal, q_positions=positions,
+                                kv_valid_len=kv_valid_len,
+                                chunk=cfg.attn_q_chunk, rules=rules)
     out = out.reshape(b, s, cfg.n_heads * hd)
-    out = proj(out, p["wo"], None, policy, rules, impl, kind="row")
+    with jax.named_scope("out"):
+        out = proj(out, p["wo"], None, policy, rules, impl, kind="row")
     if rules is not None:
         # row-parallel output lands sequence-sharded (TP path does this by
         # construction; the constraint keeps the GSPMD path on RS too, D1)
@@ -373,6 +386,7 @@ def init_mlp(key, cfg, dtype, d_ff=None):
     }
 
 
+@jax.named_scope("mlp")
 def mlp(x, p, cfg, policy, *, rules=None, impl="auto"):
     if cfg.mlp == "gated_silu" or "w_gate" in p:
         g = proj(x, p["w_gate"], None, policy, rules, impl, kind="col")

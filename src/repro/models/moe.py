@@ -214,6 +214,7 @@ def _capacity(cfg, n_tokens: int) -> int:
     return max(8, min(c, n_tokens))
 
 
+@jax.named_scope("moe")
 def moe_ffn(x, p, cfg, policy, *, rules=None, impl="auto"):
     """x [B,S,D] -> ([B,S,D], aux) where ``aux`` is the metrics dict of
     ``_aux_metrics`` (``aux["loss"]`` is what joins the objective).

@@ -50,13 +50,14 @@ class ModelApi:
         would force GSPMD to all-gather the full logits (§Perf D1).
         """
         logits, aux_loss = self.apply(params, tokens, aux=aux, **kw)
-        tgt = tokens[:, 1:]
-        lg = logits[:, :-1].astype(jnp.float32)
-        lse = jax.nn.logsumexp(lg, axis=-1)
-        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lg.shape, 2)
-        picked = jnp.sum(jnp.where(vocab_iota == tgt[..., None], lg, 0.0),
-                         axis=-1)
-        ce = jnp.mean(lse - picked)
+        with jax.named_scope("loss"):
+            tgt = tokens[:, 1:]
+            lg = logits[:, :-1].astype(jnp.float32)
+            lse = jax.nn.logsumexp(lg, axis=-1)
+            vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lg.shape, 2)
+            picked = jnp.sum(jnp.where(vocab_iota == tgt[..., None], lg,
+                                       0.0), axis=-1)
+            ce = jnp.mean(lse - picked)
         return ce + aux_loss
 
 
@@ -74,6 +75,7 @@ def _init_embed(key, cfg, dtype):
     return p
 
 
+@jax.named_scope("embed")
 def _embed(params, tokens, cfg, rules):
     from ..parallel.tp_gemm import embed_ep_applicable, embed_lookup_ep
     if rules is not None and embed_ep_applicable(tokens, params["embed"],
@@ -86,6 +88,7 @@ def _embed(params, tokens, cfg, rules):
     return x
 
 
+@jax.named_scope("head")
 def _head(params, x, cfg, policy, rules, impl):
     xn = L.apply_norm(x, params["final_norm"], cfg)
     w = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])

@@ -98,6 +98,7 @@ def _stochastic_cast(x_f32, dtype, key):
     return jnp.where(u < jnp.abs(frac), nxt, lof).astype(dtype)
 
 
+@jax.named_scope("optimizer")
 def adamw_update(grads, opt_state, params, cfg: AdamWConfig,
                  *, skip: Optional[jax.Array] = None, rng=None):
     """One step. ``skip`` (bool scalar) freezes everything (loss-scale

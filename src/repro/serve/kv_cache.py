@@ -142,7 +142,9 @@ def paged_attend(q, k_new, v_new, kv, pt, lens, *, cfg, policy,
     ``(out [B,S,H,hd], new_kv)`` — the functionally-updated pool
     leaves.  Packed pools quantize the new rows once on the way in
     (``ops.mx_quantize_kv``) and the decode kernel streams payloads;
-    carrier pools store ``k_new`` at pool dtype.
+    carrier pools store ``k_new`` at pool dtype.  The append runs under
+    the ``kv_write`` scope, the page gather under ``kv_gather``, the
+    kernel under ``core`` (``repro.scopes.ATTN``).
     """
     policy = get_policy(policy)
     b, s, h, hd = q.shape
@@ -152,26 +154,32 @@ def paged_attend(q, k_new, v_new, kv, pt, lens, *, cfg, policy,
 
     if "kp" in kv:
         name = policy.mx_kv_cache_name
-        kp, ks8 = ops.mx_quantize_kv(k_new, name, impl=impl)
-        vp, vs8 = ops.mx_quantize_kv(v_new, name, impl=impl)
-        new_kv = {"kp": kv["kp"].at[pidx, off].set(kp),
-                  "ks": kv["ks"].at[pidx, off].set(ks8),
-                  "vp": kv["vp"].at[pidx, off].set(vp),
-                  "vs": kv["vs"].at[pidx, off].set(vs8)}
-        args = [_heads_to_rows(_gather(new_kv[n], pt), h)
-                for n in ("kp", "ks", "vp", "vs")]
-        out = ops.mx_decode_attention_packed(
-            q.transpose(0, 2, 1, 3).reshape(b * h, s, hd), *args, lens_r,
-            mx_k=name, impl=impl)
+        with jax.named_scope("kv_write"):
+            kp, ks8 = ops.mx_quantize_kv(k_new, name, impl=impl)
+            vp, vs8 = ops.mx_quantize_kv(v_new, name, impl=impl)
+            new_kv = {"kp": kv["kp"].at[pidx, off].set(kp),
+                      "ks": kv["ks"].at[pidx, off].set(ks8),
+                      "vp": kv["vp"].at[pidx, off].set(vp),
+                      "vs": kv["vs"].at[pidx, off].set(vs8)}
+        with jax.named_scope("kv_gather"):
+            args = [_heads_to_rows(_gather(new_kv[n], pt), h)
+                    for n in ("kp", "ks", "vp", "vs")]
+        with jax.named_scope("core"):
+            out = ops.mx_decode_attention_packed(
+                q.transpose(0, 2, 1, 3).reshape(b * h, s, hd), *args, lens_r,
+                mx_k=name, impl=impl)
     else:
-        new_kv = {"k": kv["k"].at[pidx, off].set(k_new.astype(
-                      kv["k"].dtype)),
-                  "v": kv["v"].at[pidx, off].set(v_new.astype(
-                      kv["v"].dtype))}
-        kg = _heads_to_rows(_gather(new_kv["k"], pt), h)
-        vg = _heads_to_rows(_gather(new_kv["v"], pt), h)
-        out = ops.decode_attention(
-            q.transpose(0, 2, 1, 3).reshape(b * h, s, hd), kg, vg, lens_r,
-            impl=impl)
+        with jax.named_scope("kv_write"):
+            new_kv = {"k": kv["k"].at[pidx, off].set(k_new.astype(
+                          kv["k"].dtype)),
+                      "v": kv["v"].at[pidx, off].set(v_new.astype(
+                          kv["v"].dtype))}
+        with jax.named_scope("kv_gather"):
+            kg = _heads_to_rows(_gather(new_kv["k"], pt), h)
+            vg = _heads_to_rows(_gather(new_kv["v"], pt), h)
+        with jax.named_scope("core"):
+            out = ops.decode_attention(
+                q.transpose(0, 2, 1, 3).reshape(b * h, s, hd), kg, vg,
+                lens_r, impl=impl)
     out = out.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
     return out.astype(q.dtype), new_kv

@@ -27,6 +27,12 @@ truth and are pushed into the device cache each step — the cache's
 own ``lens + s`` advance is ignored, which is also what keeps idle
 slots from drifting.
 
+The block prefill and the lockstep decode are two jitted functions,
+``prefill_step`` and ``decode_step`` (both ``model.decode_step``), so a
+profile's ``XLA Modules`` line tells the phases apart.  Each tick opens
+the host spans of ``repro.scopes.SERVE_SPANS`` and leaves a
+``TickRecord`` in ``ticks``, the last ``TICK_RECORDS`` of them.
+
 Greedy decoding reproduces ``serve.decode.generate`` token for token:
 same kernels, same cache math — only the page *numbering* differs,
 and the gather re-assembles identical sequences either way.
@@ -34,7 +40,7 @@ and the gather re-assembles identical sequences either way.
 from __future__ import annotations
 
 import dataclasses
-import functools
+import time
 from collections import deque
 from typing import Any, Optional
 
@@ -44,7 +50,11 @@ import numpy as np
 
 from .kv_cache import max_pages
 
-__all__ = ["ServeRequest", "PageAllocator", "ContinuousBatcher"]
+__all__ = ["ServeRequest", "PageAllocator", "ContinuousBatcher",
+           "TickRecord", "TICK_RECORDS"]
+
+#: how many of the latest ticks ``ContinuousBatcher.ticks`` keeps
+TICK_RECORDS = 4096
 
 
 @dataclasses.dataclass
@@ -59,10 +69,15 @@ class PageAllocator:
 
     def __init__(self, n_pages: int):
         self._free = list(range(n_pages - 1, 0, -1))
+        self._usable = n_pages - 1
 
     @property
     def available(self) -> int:
         return len(self._free)
+
+    @property
+    def in_use(self) -> int:
+        return self._usable - len(self._free)
 
     def alloc(self, n: int) -> list[int]:
         if n > len(self._free):
@@ -82,6 +97,23 @@ class _Slot:
     remaining: int
     tok: int                      # last sampled token (next step's input)
     out: list
+
+
+@dataclasses.dataclass
+class TickRecord:
+    """One tick of ``ContinuousBatcher.step`` that admitted or decoded.
+
+    Times are ``time.perf_counter`` seconds.  ``admitted`` holds, per
+    request prefilled in the tick, ``(uid, prompt length, time its first
+    token was sampled)``; ``decoding`` the slots that ran the lockstep
+    decode; ``pages`` the pool pages in use at the tick's end; ``queue``
+    the pending requests when admission began."""
+    start: float
+    end: float
+    admitted: list
+    decoding: int
+    pages: int
+    queue: int
 
 
 class ContinuousBatcher:
@@ -112,8 +144,18 @@ class ContinuousBatcher:
         self.slots: list[Optional[_Slot]] = [None] * max_batch
         self.pending: deque[ServeRequest] = deque()
         self.done: dict[Any, np.ndarray] = {}
-        self._step = jax.jit(functools.partial(model.decode_step,
-                                               rules=rules, impl=impl))
+        self.ticks: deque[TickRecord] = deque(maxlen=TICK_RECORDS)
+
+        def prefill_step(params, tokens, cache):
+            return model.decode_step(params, tokens, cache, rules=rules,
+                                     impl=impl)
+
+        def decode_step(params, tokens, cache):
+            return model.decode_step(params, tokens, cache, rules=rules,
+                                     impl=impl)
+
+        self.prefill_step = jax.jit(prefill_step)
+        self.decode_step = jax.jit(decode_step)
 
     # ------------------------------------------------------------- state --
 
@@ -139,7 +181,7 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------- transitions --
 
-    def _admit(self):
+    def _admit(self, admitted: list):
         for b in range(self.max_batch):
             if self.slots[b] is not None or not self.pending:
                 continue
@@ -147,19 +189,23 @@ class ContinuousBatcher:
             prompt = np.asarray(req.prompt, np.int32)
             p = len(prompt)
             assert p + req.max_new_tokens <= self.max_len, req.uid
-            self.lens[b] = 0
-            for pos in range(p):
-                self._ensure(b, pos)
-            # single-slot view prefill: pool leaves are shared, so the
-            # functional update lands the pages in the common arrays
-            view = {"kv": self.cache["kv"],
-                    "pt": jnp.asarray(self.pt[b:b + 1]),
-                    "lens": jnp.zeros((1,), jnp.int32)}
-            logits, view = self._step(self.params, jnp.asarray(prompt[None]),
-                                      view)
-            self.cache = {**self.cache, "kv": view["kv"]}
-            self.lens[b] = p
-            tok = int(self._sample(logits[:, -1])[0])
+            with jax.profiler.TraceAnnotation("repro.serve.prefill",
+                                              uid=str(req.uid),
+                                              prompt_len=p):
+                self.lens[b] = 0
+                for pos in range(p):
+                    self._ensure(b, pos)
+                # single-slot view prefill: pool leaves are shared, so the
+                # functional update lands the pages in the common arrays
+                view = {"kv": self.cache["kv"],
+                        "pt": jnp.asarray(self.pt[b:b + 1]),
+                        "lens": jnp.zeros((1,), jnp.int32)}
+                logits, view = self.prefill_step(
+                    self.params, jnp.asarray(prompt[None]), view)
+                self.cache = {**self.cache, "kv": view["kv"]}
+                self.lens[b] = p
+                tok = int(self._sample(logits[:, -1])[0])
+            admitted.append((req.uid, p, time.perf_counter()))
             slot = _Slot(req, req.max_new_tokens - 1, tok, [tok])
             if self._finished(slot):
                 self._retire(b, slot)
@@ -181,30 +227,42 @@ class ContinuousBatcher:
 
     def step(self):
         """One scheduler tick: admit, lockstep-decode, retire."""
-        self._admit()
+        start, queue, admitted = time.perf_counter(), len(self.pending), []
+        with jax.profiler.TraceAnnotation("repro.serve.admit"):
+            self._admit(admitted)
         active = [b for b in range(self.max_batch)
                   if self.slots[b] is not None]
-        if not active:
-            return
+        if active:
+            self._decode(active)
+        if admitted or active:
+            self.ticks.append(TickRecord(
+                start, time.perf_counter(), admitted, len(active),
+                self.alloc.in_use, queue))
+
+    def _decode(self, active: list):
         toks = np.zeros((self.max_batch,), np.int32)
         for b in active:
             toks[b] = self.slots[b].tok
             self._ensure(b, int(self.lens[b]))
-        self._push_tables()
-        logits, new_cache = self._step(self.params, jnp.asarray(toks),
-                                       self.cache)
+        with jax.profiler.TraceAnnotation("repro.serve.push_tables"):
+            self._push_tables()
+        with jax.profiler.TraceAnnotation("repro.serve.decode"):
+            logits, new_cache = self.decode_step(
+                self.params, jnp.asarray(toks), self.cache)
         # keep the updated pool; device pt/lens are overwritten from the
         # host tables on the next push (idle slots stay pinned at 0)
         self.cache = {**self.cache, "kv": new_cache["kv"]}
-        sampled = self._sample(logits)
-        for b in active:
-            self.lens[b] += 1
-            slot = self.slots[b]
-            slot.tok = int(sampled[b])
-            slot.out.append(slot.tok)
-            slot.remaining -= 1
-            if self._finished(slot):
-                self._retire(b, slot)
+        with jax.profiler.TraceAnnotation("repro.serve.sample"):
+            sampled = self._sample(logits)
+        with jax.profiler.TraceAnnotation("repro.serve.retire"):
+            for b in active:
+                self.lens[b] += 1
+                slot = self.slots[b]
+                slot.tok = int(sampled[b])
+                slot.out.append(slot.tok)
+                slot.remaining -= 1
+                if self._finished(slot):
+                    self._retire(b, slot)
 
     def run(self, requests) -> dict:
         self.pending.extend(requests)

@@ -16,6 +16,11 @@ Fault-tolerance model (designed for 1000+ nodes, exercised in tests on 1):
   512-chip job restarts on 256 chips by just rebuilding the mesh.
 * **numeric faults**: non-finite grads skip the update (train_step),
   so a single bad batch/node cannot poison the weights.
+
+Each step's host phases run inside the spans of
+``repro.scopes.TRAIN_SPANS`` (``jax.profiler.TraceAnnotation``): the
+batch, the jitted call's dispatch, the wait for the device, the metrics'
+fetch to host floats and the checkpoint.
 """
 from __future__ import annotations
 
@@ -73,23 +78,29 @@ class Trainer:
         for step in range(self.start_step, self.start_step + num_steps):
             if self.fail_at_step is not None and step == self.fail_at_step:
                 raise RuntimeError(f"induced failure at step {step}")
-            batch = self.data.global_batch_at_step(step)
-            aux = aux_fn(step) if aux_fn else None
+            with jax.profiler.TraceAnnotation("repro.train.batch"):
+                batch = self.data.global_batch_at_step(step)
+                aux = aux_fn(step) if aux_fn else None
             t0 = time.perf_counter()
-            if aux is not None:
-                self.state, metrics = self.train_step(self.state,
-                                                      batch, aux)
-            else:
-                self.state, metrics = self.train_step(self.state, batch)
-            jax.block_until_ready(jax.tree.leaves(metrics)[0])
+            with jax.profiler.TraceAnnotation("repro.train.dispatch"):
+                if aux is not None:
+                    self.state, metrics = self.train_step(self.state,
+                                                          batch, aux)
+                else:
+                    self.state, metrics = self.train_step(self.state, batch)
+            with jax.profiler.TraceAnnotation("repro.train.wait"):
+                jax.block_until_ready(jax.tree.leaves(metrics)[0])
             dt = time.perf_counter() - t0
             self._track_straggler(step, dt)
-            metrics = {k: float(np.asarray(v)) for k, v in metrics.items()}
+            with jax.profiler.TraceAnnotation("repro.train.fetch"):
+                metrics = {k: float(np.asarray(v))
+                           for k, v in metrics.items()}
             metrics["step"] = step
             metrics["step_time_s"] = dt
             self.metrics_log.append(metrics)
             if (step + 1) % self.save_every == 0:
-                self.ckpt.save_async(step + 1, self.state)
+                with jax.profiler.TraceAnnotation("repro.train.ckpt"):
+                    self.ckpt.save_async(step + 1, self.state)
         return self.metrics_log
 
     def _track_straggler(self, step: int, dt: float):
