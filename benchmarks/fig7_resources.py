@@ -26,6 +26,8 @@ from __future__ import annotations
 
 import math
 
+from repro.kernels.exsdotp_gemm import default_blocks, vmem_bytes
+
 
 def _unit(p_src: int, p_dst: int, fused: bool) -> float:
     mul = 2 * p_src ** 2
@@ -49,12 +51,17 @@ def main():
         f = _unit(ps, pd, fused=True)
         c = _unit(ps, pd, fused=False)
         print(f"{name},{f:.0f},{c:.0f},{100*(1-f/c):.0f},~30")
-    # VMEM working set of the Pallas kernel tiles (fp8 src, fp32 acc)
-    print("kernel_tile,bm,bn,bk,vmem_bytes")
-    for bm, bn, bk, srcb in [(128, 128, 512, 1), (128, 128, 256, 2),
-                             (256, 256, 512, 1)]:
-        vmem = bm * bk * srcb + bk * bn * srcb + bm * bn * 4 + bm * bn * 2
-        print(f"exsdotp_gemm,{bm},{bn},{bk},{vmem}")
+    # VMEM working set of the Pallas kernel's default tiles at the
+    # stablelm-1.6b training shapes (fp8 sources widened to bf16, f32
+    # accumulator, bf16 out) and at an fp16 shape (f32 widening)
+    print("kernel_tile,m,k,n,bm,bn,bk,vmem_bytes")
+    for m, k, n, srcb, wideb in [(4096, 2048, 2048, 1, 2),
+                                 (4096, 5632, 2048, 1, 2),
+                                 (5632, 4096, 2048, 1, 2),
+                                 (4096, 2048, 5632, 2, 4)]:
+        bm, bn, bk = default_blocks(m, n, k, srcb, wideb)
+        vmem = vmem_bytes(bm, bn, bk, srcb, wideb, out_bytes=2)
+        print(f"exsdotp_gemm,{m},{k},{n},{bm},{bn},{bk},{vmem}")
     return None
 
 

@@ -57,6 +57,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 
 from repro.configs import get_arch  # noqa: E402  (src/ is on the path now)
 from repro.data.pipeline import DataConfig, SyntheticTokens  # noqa: E402
+from repro.kernels import ops  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.train import auto_mesh, build_trainer  # noqa: E402
 from repro.models import build_model  # noqa: E402
@@ -149,11 +150,20 @@ def run_trainer(cfg, *, batch, seq, steps, mesh=None, tag="train"):
                             mesh=mesh)
     check(trainer.start_step == 0, f"{tag}: resumed from a stale checkpoint")
     batch0 = trainer.data.global_batch_at_step(0)
+    ops.EXSDOTP_CALLS.clear()
     t0 = time.perf_counter()
     compiled = trainer.train_step.lower(trainer.state, batch0).compile()
     compile_s = time.perf_counter() - t0
+    # ExSdotp GEMMs the step traced, by (MXU operand dtype, bm, bn, bk)
+    tally = {"{}:{}x{}x{}".format(*key): c
+             for key, c in sorted(ops.EXSDOTP_CALLS.items())}
+    log(f"{tag} ExSdotp calls at trace time: {json.dumps(tally)}")
     if mesh is None:
         kernels = require_kernels(compiled, f"{tag} step")
+        check(tally and all(
+            w == "bfloat16" and min(t) >= 512
+            for (w, *t) in ops.EXSDOTP_CALLS),
+            f"{tag}: ExSdotp GEMMs off the bf16 path or its large tiles")
     else:
         # GSPMD partitions the mesh step's GEMMs, which Mosaic kernels
         # cannot be (models/layers.py proj): they run as XLA dots there
@@ -165,6 +175,7 @@ def run_trainer(cfg, *, batch, seq, steps, mesh=None, tag="train"):
     check(not any(m["skipped"] for m in hist), f"{tag}: skipped steps")
     steady = statistics.median(times[1:]) if len(times) > 1 else times[0]
     report = {"compile_s": compile_s, "kernel_calls": kernels,
+              "exsdotp_calls": tally,
               "step_s": times, "steady_step_s": steady,
               "tokens_per_s": batch * seq / steady, "losses": losses}
     return report, trainer
@@ -309,7 +320,6 @@ def kernel_checks(cfg, *, max_len, tokens=512, seed=1) -> dict:
     a 17-row block prefill whose q tile is padded) within
     ``GEMM_RTOL`` / ``ATTN_RTOL``."""
     from repro.core.policy import get_policy
-    from repro.kernels import ops
 
     pol = get_policy(cfg.policy_name)
     mx, mx_kv = pol.mx_fwd, pol.mx_kv_cache_name

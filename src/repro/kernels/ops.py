@@ -11,6 +11,7 @@ up to block multiples (zero padding is exact for GEMM and for amax).
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import os
@@ -28,12 +29,14 @@ from . import autotune, ref
 from .blockscale_gemm import (blockscale_gemm_pallas, mx_gemm_packed_pallas,
                               mx_gemm_pallas)
 from .codec import get_codec
-from .exsdotp_gemm import exsdotp_gemm_pallas, default_blocks
+from .exsdotp_gemm import (default_blocks, exsdotp_gemm_pallas,
+                           widen_dtype)
 from .quant import (mx_quant_packed_pallas, mx_quant_pallas,
                     quant_blockwise_pallas)
 
-__all__ = ["exsdotp_gemm", "blockscale_gemm", "blockscale_blocks",
-           "quantize_tensor", "quantize_blockwise", "dequantize_blockwise",
+__all__ = ["exsdotp_gemm", "EXSDOTP_CALLS", "blockscale_gemm",
+           "blockscale_blocks", "quantize_tensor", "quantize_blockwise",
+           "dequantize_blockwise",
            "mx_quantize", "mx_dequantize", "mx_dequantize_packed",
            "mx_gemm", "mx_blocks", "mx_packed_blocks",
            "mx_pack", "mx_unpack", "mx_gemm_packed",
@@ -83,20 +86,35 @@ def _pad_last2(x, br, bc):
     return x
 
 
+#: trace-time tally of ExSdotp kernel calls by (MXU operand dtype, bm, bn,
+#: bk): one count each time a program traces ``exsdotp_gemm`` on a Pallas
+#: impl (``chip_smoke.py`` prints it after its training step)
+EXSDOTP_CALLS: collections.Counter = collections.Counter()
+
+
 def exsdotp_gemm(a: jax.Array, b: jax.Array, scale=1.0, *,
                  out_dtype=jnp.float32, impl: str = "auto",
                  blocks=None) -> jax.Array:
-    """Expanding GEMM: downcast(scale * A @ B) with fp32 accumulation."""
+    """Expanding GEMM: downcast(scale * A @ B) with fp32 accumulation.
+
+    The kernel's tiles (``default_blocks``) and MXU operand dtype
+    (``widen_dtype``: bf16 for 8-bit and bf16 sources, f32 for fp16)
+    follow from the shapes and source formats alone."""
     impl = resolve_impl(impl)
     if impl == "xla":
         return ref.exsdotp_gemm_ref(a, b, scale, out_dtype=out_dtype)
     m, k = a.shape
     _, n = b.shape
-    bm, bn, bk = blocks or default_blocks(m, n, k, a.dtype.itemsize)
-    a, fmt_a = _kernel_operand(_pad2(a, bm, bk))
-    b, fmt_b = _kernel_operand(_pad2(b, bk, bn))
+    a, fmt_a = _kernel_operand(a)
+    b, fmt_b = _kernel_operand(b)
+    wide = jnp.dtype(widen_dtype(a.dtype, fmt_a, b.dtype, fmt_b))
+    bm, bn, bk = blocks or default_blocks(
+        m, n, k, max(a.dtype.itemsize, b.dtype.itemsize), wide.itemsize,
+        jnp.dtype(out_dtype).itemsize)
+    EXSDOTP_CALLS[(wide.name, bm, bn, bk)] += 1
     out = exsdotp_gemm_pallas(
-        a, b, jnp.asarray(scale, jnp.float32).reshape(1, 1),
+        _pad2(a, bm, bk), _pad2(b, bk, bn),
+        jnp.asarray(scale, jnp.float32).reshape(1, 1),
         out_dtype=out_dtype, block_m=bm, block_n=bn, block_k=bk,
         fmt_a=fmt_a, fmt_b=fmt_b, interpret=(impl == "pallas_interpret"))
     return out[:m, :n]

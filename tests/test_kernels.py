@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core.formats import get_format
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(3)
@@ -136,6 +137,190 @@ def test_property_gemm_any_shape(mb, kb, nb, seed):
                            impl="pallas_interpret", blocks=(8, 8, 16))
     want = ref.exsdotp_gemm_ref(a, b, 1.0, out_dtype=jnp.float32)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+# ------------------------------------------- ExSdotp widening and tiles ---
+
+FP8 = {"e4m3": (jnp.float8_e4m3, "fp8alt"), "e5m2": (jnp.float8_e5m2, "fp8")}
+FP8_PAIRS = [("e4m3", "e4m3"), ("e5m2", "e4m3"), ("e4m3", "e5m2"),
+             ("e5m2", "e5m2")]
+#: (m, k, n) of train-stablelm-l6's ExSdotp GEMMs: forward, dgrad, wgrad
+CELL_SHAPES = [(4096, 2048, 2048), (4096, 2048, 5632), (4096, 5632, 2048),
+               (2048, 4096, 2048), (2048, 4096, 5632), (5632, 4096, 2048)]
+
+
+def _fp8_edge_operand(shape, name, rng, specials=False):
+    """Random fp8 values with the format's edges planted in: ±0, the
+    smallest and largest subnormal, ±max normal (and inf/NaN codes)."""
+    dtype, fmt = FP8[name]
+    f = get_format(fmt)
+    x = rng.normal(0, 1, shape)
+    edges = [0.0, -0.0, f.min_subnormal, -f.min_subnormal,
+             f.min_normal - f.min_subnormal, f.max_normal, -f.max_normal]
+    if specials:
+        edges += [np.inf, -np.inf, np.nan]
+    idx = rng.choice(x.size, len(edges), replace=False)
+    x.flat[idx] = edges
+    return jnp.asarray(x, dtype)
+
+
+@pytest.mark.parametrize("fmt", ["fp8", "fp8alt"])
+def test_widen_bits_bf16_is_decode_exactly(fmt):
+    """The kernel's bf16 widening of every 8-bit code is bit for bit
+    ``decode``'s value in bf16: subnormals, ±0, ±max, inf; NaN stays NaN."""
+    from repro.core.formats import decode
+    from repro.kernels.exsdotp_gemm import _widen_bits_bf16
+    codes = jnp.arange(256, dtype=jnp.uint8).reshape(2, 128)
+    got = np.asarray(_widen_bits_bf16(codes, fmt), np.float32)
+    want = np.asarray(decode(codes, fmt), np.float32)
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
+@pytest.mark.parametrize("pair", FP8_PAIRS, ids="-".join)
+def test_gemm_fp8_pairs_match_oracles(pair):
+    """Every pair of 8-bit sources takes the bf16 path at the default
+    tiles and matches ``exsdotp_gemm_ref`` and the ``exsdotp_gemm_np``
+    ExSdotp chain (fp16 holds every fp8/fp8alt value) within f32
+    summation order: |err| <= K 2^-23 (|A| @ |B|)."""
+    from repro.core.exsdotp import exsdotp_gemm_np
+    from repro.kernels.exsdotp_gemm import widen_dtype
+    rng = np.random.default_rng(11)
+    m, k, n = 40, 200, 72
+    a = _fp8_edge_operand((m, k), pair[0], rng)
+    b = _fp8_edge_operand((k, n), pair[1], rng)
+    assert widen_dtype(a.dtype, None, b.dtype, None) == jnp.bfloat16
+    out = np.asarray(ops.exsdotp_gemm(a, b, 1.0, impl="pallas_interpret"),
+                     np.float64)
+    af, bf = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    bound = k * 2.0 ** -23 * (np.abs(af) @ np.abs(bf))
+    for want in (np.asarray(ref.exsdotp_gemm_ref(a, b, 1.0), np.float64),
+                 exsdotp_gemm_np(af, bf, "fp16", "fp32")):
+        assert (np.abs(out - want) <= bound).all()
+
+
+@pytest.mark.parametrize("pair", FP8_PAIRS, ids="-".join)
+def test_gemm_fp8_specials_match_ref(pair):
+    """inf/NaN codes of each format reach the output as the f32 upcast
+    gives them: the same NaN and ±inf entries, finite ones close."""
+    rng = np.random.default_rng(12)
+    a = _fp8_edge_operand((24, 256), pair[0], rng, specials=True)
+    b = _fp8_edge_operand((256, 16), pair[1], rng, specials=True)
+    out = np.asarray(ops.exsdotp_gemm(a, b, 1.0, impl="pallas_interpret"))
+    want = np.asarray(ref.exsdotp_gemm_ref(a, b, 1.0))
+    np.testing.assert_array_equal(np.isnan(out), np.isnan(want))
+    np.testing.assert_array_equal(np.isinf(out), np.isinf(want))
+    fin = np.isfinite(want)
+    np.testing.assert_array_equal(out[~fin & ~np.isnan(want)],
+                                  want[~fin & ~np.isnan(want)])
+    np.testing.assert_allclose(out[fin], want[fin], rtol=1e-5,
+                               atol=1e-5 * np.abs(want[fin]).max())
+
+
+@pytest.mark.parametrize("pair", FP8_PAIRS, ids="-".join)
+@pytest.mark.parametrize("out_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=lambda d: d.__name__)
+def test_gemm_fp8_bit_exact_on_integer_inputs(pair, out_dtype):
+    """Integer-valued fp8 operands through the bf16 path at the default
+    tiles: exact f32 accumulation, so equal to the f32 upcast bit for
+    bit, final rounding included."""
+    rng = np.random.default_rng(13)
+    a = jnp.asarray(rng.integers(-8, 9, (48, 384)), FP8[pair[0]][0])
+    b = jnp.asarray(rng.integers(-8, 9, (384, 136)), FP8[pair[1]][0])
+    out = ops.exsdotp_gemm(a, b, 1.0, out_dtype=out_dtype,
+                           impl="pallas_interpret")
+    want = ref.exsdotp_gemm_ref(a, b, 1.0, out_dtype=out_dtype)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("src", [jnp.float16, jnp.bfloat16,
+                                 jnp.float8_e4m3, jnp.float8_e5m2],
+                         ids=lambda d: d.__name__)
+def test_gemm_widening_follows_source_format(src):
+    """fp16 (11 significant bits) keeps the f32 upcast; bf16 and the
+    8-bit formats widen to bf16 — the tally records which path ran."""
+    want = "float32" if src == jnp.float16 else "bfloat16"
+    a = jnp.asarray(RNG.normal(0, 1, (16, 128)), src)
+    b = jnp.asarray(RNG.normal(0, 1, (128, 8)), src)
+    ops.EXSDOTP_CALLS.clear()
+    out = ops.exsdotp_gemm(a, b, 1.0, impl="pallas_interpret")
+    assert [key[0] for key in ops.EXSDOTP_CALLS] == [want]
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref.exsdotp_gemm_ref(a, b, 1.0)),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("m", [1, 8, 40])
+@pytest.mark.parametrize("n", [128, 200])
+@pytest.mark.parametrize("k", [128, 200])
+@pytest.mark.parametrize("src_bytes", [1, 2])
+def test_default_blocks_legal_for_small_shapes(m, n, k, src_bytes):
+    """Small problems get legal tiles shrunk to them: M a sublane
+    multiple of 8 covering the row count, N and K lane multiples of 128
+    no larger than the padded dims."""
+    from repro.kernels.exsdotp_gemm import default_blocks
+    bm, bn, bk = default_blocks(m, n, k, src_bytes, 2 * src_bytes)
+    assert bm % 8 == 0 and m <= bm < m + 8
+    assert bn % 128 == 0 and n <= bn < n + 128
+    assert bk % 128 == 0 and k <= bk < k + 128
+
+
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=str)
+def test_default_blocks_large_at_cell_shapes(shape):
+    """At train-stablelm-l6's GEMM shapes every tile is >= 512 (each
+    widened element feeds >= 512 MACs), the tiles divide the shape, fit
+    the VMEM budget, and the grid has >= 16x fewer steps than the
+    128 x 128 x 512 tiles had."""
+    from repro.kernels.exsdotp_gemm import (VMEM_BUDGET, default_blocks,
+                                            vmem_bytes)
+    m, k, n = shape
+    bm, bn, bk = default_blocks(m, n, k, 1, 2)
+    assert min(bm, bn, bk) >= 512
+    assert m % bm == 0 and n % bn == 0 and k % bk == 0
+    assert vmem_bytes(bm, bn, bk, 1, 2) <= VMEM_BUDGET
+    old = (m // 128) * (n // 128) * (k // 512)
+    assert old >= 16 * (m // bm) * (n // bn) * (k // bk)
+
+
+def test_tally_hfp8_qlinear_takes_bf16_path():
+    """An hfp8 ``qlinear`` forward and backward traces three ExSdotp
+    GEMMs (forward, dgrad, wgrad), all on the bf16 path."""
+    from repro.core.linear import qlinear
+    x = jnp.asarray(RNG.normal(0, 1, (2, 64, 256)), jnp.float32)
+    w = jnp.asarray(RNG.normal(0, 0.05, (256, 384)), jnp.float32)
+    ops.EXSDOTP_CALLS.clear()
+    jax.make_jaxpr(jax.grad(
+        lambda x, w: jnp.sum(qlinear(x, w, "hfp8",
+                                     impl="pallas_interpret") ** 2),
+        argnums=(0, 1)))(x, w)
+    assert sum(ops.EXSDOTP_CALLS.values()) == 3
+    assert {key[0] for key in ops.EXSDOTP_CALLS} == {"bfloat16"}
+
+
+def test_tally_stablelm_hfp8_step_takes_large_bf16_tiles():
+    """The train-stablelm-l6 step (stablelm-1.6b widths under hfp8, one
+    scanned layer, 1 x 4096 tokens), traced for the Pallas path: every
+    ExSdotp GEMM widens to bf16 with tiles of >= 512."""
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.models import build_model
+    from repro.optim.adamw import AdamWConfig
+    from repro.train.train_step import make_train_state, make_train_step
+    cfg = dataclasses.replace(get_arch("stablelm-1.6b"), n_layers=1)
+    assert cfg.policy_name == "hfp8"
+    model = build_model(cfg)
+    opt = AdamWConfig(lr=1e-3, warmup_steps=1, schedule="constant")
+    state = jax.eval_shape(
+        lambda: make_train_state(model, jax.random.key(0), opt))
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32)
+    ops.EXSDOTP_CALLS.clear()
+    jax.eval_shape(make_train_step(model, opt, impl="pallas"), state, tokens)
+    assert sum(ops.EXSDOTP_CALLS.values()) >= 21  # 7 projections x 3
+    for wide, bm, bn, bk in ops.EXSDOTP_CALLS:
+        assert wide == "bfloat16" and min(bm, bn, bk) >= 512
 
 
 # ------------------------------------------------------ flash attention ---

@@ -67,6 +67,32 @@ def test_exsdotp_gemm_compiles(one_chip, dtype):
              _spec(one_chip, (TRAIN_K, TRAIN_N), dtype))
 
 
+#: train-stablelm-l6's ExSdotp GEMMs (M, K, N): E4M3 forward, E5M2 x
+#: E4M3^T dgrad, E4M3^T x E5M2 wgrad (M = d_ff for w_down's), 4096 tokens
+E4, E5 = ml_dtypes.float8_e4m3, ml_dtypes.float8_e5m2
+CELL_GEMMS = {
+    "fwd-K2048": (E4, E4, TRAIN_M, TRAIN_K, TRAIN_K),
+    "fwd-K5632": (E4, E4, TRAIN_M, TRAIN_N, TRAIN_K),
+    "dgrad-K2048": (E5, E4, TRAIN_M, TRAIN_K, TRAIN_N),
+    "dgrad-K5632": (E5, E4, TRAIN_M, TRAIN_N, TRAIN_K),
+    "wgrad-M2048": (E4, E5, TRAIN_K, TRAIN_M, TRAIN_N),
+    "wgrad-M5632": (E4, E5, TRAIN_N, TRAIN_M, TRAIN_K),
+}
+
+
+@pytest.mark.parametrize("gemm", list(CELL_GEMMS))
+def test_exsdotp_gemm_cell_tiles_compile(one_chip, gemm):
+    """The bf16-widening kernel at its large default tiles within the
+    16 MiB VMEM budget, at each kind of the training cell's GEMM shapes."""
+    da, db, m, k, n = CELL_GEMMS[gemm]
+    ops.EXSDOTP_CALLS.clear()
+    _compile(lambda a, b: ops.exsdotp_gemm(a, b, 0.5, out_dtype=jnp.bfloat16,
+                                           impl="pallas"),
+             _spec(one_chip, (m, k), da), _spec(one_chip, (k, n), db))
+    (wide, bm, bn, bk), = ops.EXSDOTP_CALLS
+    assert wide == "bfloat16" and min(bm, bn, bk) >= 512
+
+
 @pytest.mark.parametrize("mx", ["mxfp8e4m3", "mxfp4e2m1"])
 def test_packed_quantize_compiles(one_chip, mx):
     _compile(lambda x: ops.mx_quantize(x, mx, impl="pallas", packed=True),
